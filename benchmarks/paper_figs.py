@@ -267,4 +267,6 @@ def run_all(full: bool = False):
 
 if __name__ == "__main__":
     import sys
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run_all(full="--full" in sys.argv)

@@ -87,6 +87,20 @@ def _stamp_git(point: dict) -> dict:
     return point
 
 
+def device_info() -> Dict[str, object]:
+    """The device JAX runs on, as every device-measured result names it:
+    platform, device kind and device count."""
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind,
+            "device_count": jax.device_count()}
+
+
+def _device_tag() -> str:
+    i = device_info()
+    return f"[{i['platform']} {i['device_kind']} x{i['device_count']}]"
+
+
 # Memoized: run_all prints a full iteration table and emit_bench_point
 # re-reads three of the same cells — don't pay for the simulation twice.
 @functools.lru_cache(maxsize=None)
@@ -232,7 +246,7 @@ def kernel_vs_engine_throughput(n_servers: int = 100,
     out["bit_exact"] = bool((chosen["jax"] == chosen["kernel"]).all())
     print(f"\n== kernel vs JAX engine scheduling throughput "
           f"({n_servers} OSS x {n_requests} reqs, transient trace, "
-          f"median of {reps}) ==")
+          f"median of {reps}) == {_device_tag()}")
     print(f"{'backend':>8s} {'wall_s':>8s} {'req/s':>10s}")
     for b in ("jax", "kernel"):
         print(f"{b:>8s} {out[f'{b}_s']:8.3f} {out[f'{b}_req_s']:10.0f}")
@@ -316,7 +330,7 @@ def kernel_batch_throughput(n_servers: int = 100, n_requests: int = 2000,
                  == np.asarray(seq.phase_time)).all())
     print(f"\n== trial-grid kernel sweep throughput ({n_servers} OSS x "
           f"{n_requests} reqs x {n_trials} trials, policy={policy}, "
-          f"median of {reps}) ==")
+          f"median of {reps}) == {_device_tag()}")
     print(f"  one pallas_call for the whole sweep: {dt:8.3f}s  "
           f"{out['kernel_batch_req_s']:10.0f} req/s aggregate")
     if measure_engine:
@@ -384,7 +398,7 @@ def kernel_per_client_throughput(n_servers: int = 100,
                       "window_loads", "phase_time", "probe_msgs")))
     print(f"\n== per_client 2-D grid sweep throughput ({n_servers} OSS x "
           f"{n_requests} reqs x {n_trials} trials x {n_clients} clients, "
-          f"policy={policy}, median of {reps}) ==")
+          f"policy={policy}, median of {reps}) == {_device_tag()}")
     for tag in ("kernel", "jax"):
         print(f"  {tag:>6s}: {out[f'per_client_{tag}_s']:8.3f}s  "
               f"{out[f'per_client_{tag}_req_s']:10.0f} req/s aggregate")
@@ -461,7 +475,8 @@ def tuned_kernel_throughput(n_servers: int = 100, n_requests: int = 2000,
             else "trial grid")
     print(f"\n== tuned-lowering sweep throughput ({n_servers} OSS x "
           f"{n_requests} reqs x {n_trials} trials, {form}, "
-          f"policy={policy}, interleaved best of {reps}) ==")
+          f"policy={policy}, interleaved best of {reps}) == "
+          f"{_device_tag()}")
     for mode in ("default", "tuned"):
         print(f"  {mode:>8s} tiles: {out[f'{mode}_s']:8.3f}s  "
               f"{out[f'{mode}_req_s']:10.0f} req/s aggregate")
@@ -492,7 +507,7 @@ def kernel_phase_profile_point(n_servers: int = 100,
     gap = max(phases, key=lambda k: phases[k])
     print(f"\n== kernel per-phase profile ({n_servers} OSS x "
           f"{n_requests} reqs x {n_trials} trials, differential over "
-          f"ablate levels, median of {reps}) ==")
+          f"ablate levels, median of {reps}) == {_device_tag()}")
     for k in ("total_s",) + tuple(phases):
         frac = prof[k] / max(prof["total_s"], 1e-12)
         print(f"  {k:>11s}: {prof[k]:8.3f}s  ({100 * frac:5.1f}%)")
@@ -547,7 +562,7 @@ def per_client_phase_breakdown(n_servers: int = 100,
     bit_exact = True
     print(f"\n== per_client batched-pipeline breakdown ({n_servers} OSS x "
           f"{n_requests} reqs x {n_trials} trials x {n_clients} clients, "
-          f"policy={policy}, median of {reps}) ==")
+          f"policy={policy}, median of {reps}) == {_device_tag()}")
     for backend in ("kernel", "jax"):
         cfg = SimConfig(n_servers=n_servers, n_requests=n_requests,
                         n_trials=n_trials, window_size=window_size,
@@ -602,8 +617,23 @@ def per_client_phase_breakdown(n_servers: int = 100,
     return out
 
 
+def _forced_host_devices() -> bool:
+    """True when JAX is held to the CPU (``JAX_PLATFORMS=cpu``), where
+    each sharded device count needs its own process with forced host
+    devices.  Decided from the environment, so the parent can start
+    those processes before it touches JAX itself."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def _sharded_devices_requested() -> tuple:
+    """Device counts of the sharded series: ``SCHED_SHARDED_DEVICES``
+    (comma list, default "1,2,4,8"; empty skips the series)."""
+    env = os.environ.get("SCHED_SHARDED_DEVICES", "1,2,4,8")
+    return tuple(int(t) for t in env.split(",") if t.strip())
+
+
 def _sharded_env(n_devices: int) -> Dict[str, str]:
-    """Env for a sharded-worker subprocess: force ``n_devices`` host
+    """Env for a CPU sharded-worker subprocess: force ``n_devices`` host
     devices (replacing any count already in XLA_FLAGS) and make sure
     ``src`` is importable."""
     env = dict(os.environ)
@@ -618,23 +648,22 @@ def _sharded_env(n_devices: int) -> Dict[str, str]:
     return env
 
 
-def _sharded_worker(spec: dict) -> None:
-    """Body of one ``--sharded-worker`` subprocess: run the 100-OSS
-    transient Monte-Carlo sweep at ``mesh_shape=(devices,)`` (plain
-    single-device dispatch when ``devices == 1``) on BOTH backends and
-    print one ``SHARDED_RESULT`` json line with req/s plus a sha1 digest
-    of the per-trial decisions/latencies/phase times — the parent
-    compares digests across device counts for the DESIGN.md §12
-    bit-exactness claim."""
+def _sharded_row(spec: dict, d: int) -> Dict[str, object]:
+    """The 100-OSS transient Monte-Carlo sweep at ``mesh_shape=(d,)``
+    over the first ``d`` devices of this process (plain single-device
+    dispatch when ``d == 1``) on BOTH backends: req/s plus a sha1 digest
+    of the per-trial decisions/latencies/phase times — compared across
+    device counts for the DESIGN.md §12 bit-exactness claim."""
     import jax
     from repro.core import simulate
     from repro.core.simulate import ScenarioConfig, SimConfig
 
-    d = int(spec["devices"])
-    assert jax.device_count() == d, (jax.device_count(), d)
+    if jax.device_count() % d:
+        raise ValueError(f"{d} devices do not divide jax.device_count()="
+                         f"{jax.device_count()}")
     key = jax.random.key(0)
     pol = PolicyConfig(name="ect", threshold=0.05)
-    out: Dict[str, object] = {"devices": d}
+    out: Dict[str, object] = {"devices": d, **device_info()}
     for backend in ("kernel", "jax"):
         cfg = SimConfig(n_servers=spec["n_servers"],
                         n_requests=spec["n_requests"],
@@ -652,7 +681,19 @@ def _sharded_worker(spec: dict) -> None:
         out[f"{backend}_s"] = dt
         out[f"{backend}_req_s"] = spec["n_trials"] * spec["n_requests"] / dt
         out[f"{backend}_digest"] = h.hexdigest()
-    print("SHARDED_RESULT " + json.dumps(out), flush=True)
+    return out
+
+
+def _sharded_worker(spec: dict) -> None:
+    """Body of one CPU ``--sharded-worker`` subprocess: its forced host
+    device count must be exactly ``spec["devices"]``; prints one
+    ``SHARDED_RESULT`` json line."""
+    import jax
+    d = int(spec["devices"])
+    if jax.device_count() != d:
+        raise RuntimeError(f"sharded worker expected {d} forced host "
+                           f"devices, JAX sees {jax.device_count()}")
+    print("SHARDED_RESULT " + json.dumps(_sharded_row(spec, d)), flush=True)
 
 
 @functools.lru_cache(maxsize=None)   # run_all + emit_bench_point share it
@@ -662,41 +703,51 @@ def sharded_sweep_throughput(n_servers: int = 100, n_requests: int = 2000,
                              devices: tuple = (1, 2, 4, 8)
                              ) -> Dict[str, object]:
     """Sharded sweep throughput (DESIGN.md §12): the full Monte-Carlo
-    sweep through ``parallel/sweep.py`` at each host device count in
+    sweep through ``parallel/sweep.py`` at each device count in
     ``devices``, both backends, vs the single-device dispatch (the
-    ``devices == 1`` row).
+    ``devices == 1`` row).  The digests of (chosen, latencies,
+    phase_time) must agree across every device count AND across
+    backends — the sharded dispatch is a pure re-layout.
 
-    Each device count runs in its own SUBPROCESS under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — XLA fixes
-    the host device count at first jax init, so one process cannot
-    measure two counts.  The workers' sha1 digests of (chosen,
-    latencies, phase_time) must agree across every device count AND
-    across backends — the sharded dispatch is a pure re-layout.
-
-    Scaling honesty: forced host "devices" are threads on the same CPU,
-    so aggregate req/s tracks ``min(devices, physical cores)`` — on a
-    1-core CI box the sharded rows measure dispatch overhead, not
-    speedup; the series exists so multi-core/multi-chip runs of the same
-    benchmark expose real scaling against the same baseline."""
+    On a chip (or any backend but the forced-CPU one) every count runs
+    in THIS process over a sub-mesh of its first ``d`` devices, and only
+    the counts that divide ``jax.device_count()`` are measured: a chip
+    belongs to one process, so no child could reach it.  Under
+    ``JAX_PLATFORMS=cpu`` each count runs in its own SUBPROCESS with
+    ``--xla_force_host_platform_device_count=N`` (XLA fixes the host
+    device count at first jax init); call this before the parent
+    touches JAX.  Forced host "devices" are threads on the same CPU, so
+    those rows measure dispatch overhead, not scaling."""
     spec = {"n_servers": n_servers, "n_requests": n_requests,
             "window_size": window_size, "n_trials": n_trials, "reps": reps}
     out: Dict[str, object] = dict(spec)
-    out["devices"] = list(devices)
     rows = {}
-    for d in devices:
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.sched_perf",
-             "--sharded-worker", json.dumps({**spec, "devices": d})],
-            cwd=_REPO_ROOT, env=_sharded_env(d),
-            capture_output=True, text=True)
-        line = next((ln for ln in r.stdout.splitlines()
-                     if ln.startswith("SHARDED_RESULT ")), None)
-        if r.returncode != 0 or line is None:
-            raise RuntimeError(
-                f"sharded worker (devices={d}) failed:\n"
-                f"{r.stdout[-2000:]}\n{r.stderr[-2000:]}")
-        rows[d] = json.loads(line[len("SHARDED_RESULT "):])
+    if _forced_host_devices():
+        for d in devices:
+            r = subprocess.run(
+                [sys.executable, "-m", "benchmarks.sched_perf",
+                 "--sharded-worker", json.dumps({**spec, "devices": d})],
+                cwd=_REPO_ROOT, env=_sharded_env(d),
+                capture_output=True, text=True)
+            line = next((ln for ln in r.stdout.splitlines()
+                         if ln.startswith("SHARDED_RESULT ")), None)
+            if r.returncode != 0 or line is None:
+                raise RuntimeError(
+                    f"sharded worker (devices={d}) failed:\n"
+                    f"{r.stdout[-2000:]}\n{r.stderr[-2000:]}")
+            rows[d] = json.loads(line[len("SHARDED_RESULT "):])
+    else:
+        import jax
+        n = jax.device_count()
+        devices = tuple(d for d in devices if n % d == 0)
+        for d in devices:
+            rows[d] = _sharded_row(spec, d)
+    out["devices"] = list(devices)
+    if not devices:
+        return out
     base = rows[devices[0]]
+    out["platform"] = base["platform"]
+    out["device_kind"] = base["device_kind"]
     out["sharded_bit_exact"] = all(
         rows[d][f"{b}_digest"] == base[f"{b}_digest"]
         for d in devices for b in ("kernel", "jax"))
@@ -707,7 +758,8 @@ def sharded_sweep_throughput(n_servers: int = 100, n_requests: int = 2000,
         out[f"sharded_engine_req_s_{d}d"] = rows[d]["jax_req_s"]
     print(f"\n== sharded sweep throughput ({n_servers} OSS x "
           f"{n_requests} reqs x {n_trials} trials, mesh=(d,), "
-          f"median of {reps}) ==")
+          f"median of {reps}) == [{base['platform']} "
+          f"{base['device_kind']}, d devices of the row]")
     print(f"{'devices':>8s} {'kernel req/s':>14s} {'engine req/s':>14s}")
     for d in devices:
         print(f"{d:>8d} {out[f'sharded_req_s_{d}d']:14.0f} "
@@ -727,7 +779,7 @@ def scenario_ranking(n_trials: int = 25) -> Dict[str, Dict[str, dict]]:
     out = _scenario_sweep(SWEEP_SCENARIOS, SWEEP_POLICIES, n_trials)
     table: Dict[str, Dict[str, dict]] = {}
     print("\n== Temporal scenarios: policy ranking "
-          "(est. completion latency, s) ==")
+          f"(est. completion latency, s) == {_device_tag()}")
     for scn, row in out.items():
         print(f"\n-- scenario: {scn} --")
         print(f"{'policy':>8s} {'p50':>8s} {'p95':>8s} {'p99':>8s} "
@@ -752,7 +804,8 @@ def transient_latency_cdf(n_trials: int = 25) -> None:
     """Latency CDF under the transient straggler trace (rr vs trh vs ect)."""
     from repro.core import analysis
     out = _transient_results(n_trials)
-    print("\n== Transient stragglers: request latency CDF ==")
+    print(f"\n== Transient stragglers: request latency CDF == "
+          f"{_device_tag()}")
     for pol, res in out.items():
         xs, ys = analysis.latency_cdf(res.latencies, 72)
         print(analysis.ascii_plot(
@@ -772,7 +825,9 @@ def emit_bench_point(path: str = BENCH_PATH,
     100 OSS x 2000 req x ``batch_trials`` sweep as ONE pallas_call), and
     the sharded-sweep series (`sharded_req_s_{d}d`, DESIGN.md §12) at
     the device counts in ``SCHED_SHARDED_DEVICES`` (comma list, default
-    "1,2,4,8"; set empty to skip the subprocess sweeps).
+    "1,2,4,8"; set empty to skip the sharded sweeps; on a chip only the
+    counts dividing the device count run).  The point names the device
+    (``platform``, ``device_kind``, ``device_count``).
     All throughput cells are medians of ``reps`` repeats (recorded in
     the point).  Points are keyed by ``git_sha``: re-running on the same
     commit REPLACES that commit's point instead of appending a
@@ -782,7 +837,8 @@ def emit_bench_point(path: str = BENCH_PATH,
     (``engine_req_s_{mlml,nltr}``) for the behind-engine flag.
     Reuses this process's cached run_all results."""
     from repro.core import analysis
-    point: Dict[str, object] = {"ts": time.time(), "metric_unit": "seconds"}
+    point: Dict[str, object] = {"ts": time.time(), "metric_unit": "seconds",
+                                **device_info()}
     # call signatures mirror run_all's rows so the lru_cache hits
     for pol, kw in (("rr", {}), ("trh", {"threshold": 4.0}),
                     ("ect", {"threshold": 0.05})):
@@ -841,20 +897,21 @@ def emit_bench_point(path: str = BENCH_PATH,
         point[k] = pb[k]
     point["e2e_batched_bit_exact"] = pb["e2e_batched_bit_exact"]
     # sharded sweep series (DESIGN.md §12): the same full-scale sweep
-    # through parallel/sweep.py at forced host device counts, one
-    # subprocess each; env-gated because each count pays its own
-    # compile + warmup
-    dev_env = os.environ.get("SCHED_SHARDED_DEVICES", "1,2,4,8")
-    devs = tuple(int(t) for t in dev_env.split(",") if t.strip())
+    # through parallel/sweep.py at each device count (see
+    # sharded_sweep_throughput); env-gated because each count pays its
+    # own compile + warmup
+    devs = _sharded_devices_requested()
     if devs:
         sh = sharded_sweep_throughput(n_servers=kernel_scale,
                                       n_trials=batch_trials, devices=devs)
-        for d in devs:
+        for d in sh["devices"]:
             point[f"sharded_req_s_{d}d"] = sh[f"sharded_req_s_{d}d"]
             point[f"sharded_engine_req_s_{d}d"] = \
                 sh[f"sharded_engine_req_s_{d}d"]
-        point["sharded_bit_exact"] = bool(
-            sh["sharded_bit_exact"] and sh["sharded_cross_backend_exact"])
+        if sh["devices"]:
+            point["sharded_bit_exact"] = bool(
+                sh["sharded_bit_exact"]
+                and sh["sharded_cross_backend_exact"])
     # §16 tuned-lowering series: the same full-scale sweeps with tiles
     # resolved through the tuner table (fused-resolver fallback on a
     # cache miss), each with its bit-exact flag vs the default lowering
@@ -1360,6 +1417,8 @@ if __name__ == "__main__":
         print(f"[sched_perf] jax.profiler trace -> {_dir}")
         _ctx.__enter__()
     try:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
         if "--sharded-worker" in sys.argv:
             _sharded_worker(
                 json.loads(sys.argv[sys.argv.index("--sharded-worker") + 1]))
@@ -1368,6 +1427,12 @@ if __name__ == "__main__":
         elif "--trajectory" in sys.argv:
             trajectory()
         else:
+            if _forced_host_devices() and _sharded_devices_requested():
+                # the CPU workers start before this process touches JAX;
+                # emit_bench_point's identical call reuses the result
+                sharded_sweep_throughput(
+                    n_servers=100, n_trials=100,
+                    devices=_sharded_devices_requested())
             run_all()
             emit_bench_point()
     finally:

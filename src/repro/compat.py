@@ -1,20 +1,12 @@
-"""JAX version-tolerance shims.
-
-The repo targets current JAX APIs; the container (and some CI images) run
-older releases.  Everything version-sensitive funnels through here so the
-rest of the code reads as if on the newest API:
+"""The few JAX calls the repo makes in several places, in one form each.
 
 * ``simple_keystr``  — ``jax.tree_util.keystr(kp, simple=True,
-  separator="/")`` (newer JAX) on any version.  Checkpoint manifests,
-  sharding rules and the optimizer's decay mask key off these stable
-  path strings.
-* ``make_mesh``      — ``jax.make_mesh`` with ``axis_types=Auto``
-  (newer JAX) falling back to the positional form.
-* ``shard_map``      — ``jax.shard_map`` falling back to
-  ``jax.experimental.shard_map.shard_map``.
+  separator="/")``.  Checkpoint manifests, sharding rules and the
+  optimizer's decay mask key off these stable path strings.
+* ``make_mesh``      — ``jax.make_mesh`` with ``axis_types=Auto``.
+* ``shard_map``      — ``jax.shard_map``.
 * ``shard_map_unchecked`` — ``shard_map`` with the static replication
-  check disabled on every version (``check_rep=False`` on older
-  releases, ``check_vma=False`` after the rename).
+  check disabled (``check_vma=False``).
 """
 
 from __future__ import annotations
@@ -22,30 +14,12 @@ from __future__ import annotations
 import jax
 import jax.tree_util as jtu
 
-try:  # newer JAX: top-level shard_map
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def _simple_key(k) -> str:
-    if isinstance(k, jtu.GetAttrKey):
-        return k.name
-    if isinstance(k, jtu.DictKey):
-        return str(k.key)
-    if isinstance(k, jtu.SequenceKey):
-        return str(k.idx)
-    if isinstance(k, jtu.FlattenedIndexKey):
-        return str(k.key)
-    return str(k)
+shard_map = jax.shard_map
 
 
 def simple_keystr(kp, separator: str = "/") -> str:
-    """`keystr(kp, simple=True, separator=...)` on every JAX version."""
-    try:
-        return jtu.keystr(kp, simple=True, separator=separator)
-    except TypeError:
-        return separator.join(_simple_key(k) for k in kp)
+    """`keystr(kp, simple=True, separator=...)`."""
+    return jtu.keystr(kp, simple=True, separator=separator)
 
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
@@ -57,22 +31,14 @@ def shard_map_unchecked(f, mesh, in_specs, out_specs):
     through tree_sum's pad/slice ops and rejects the ``out_specs``, so
     the check is turned off (the replication is real: every device
     gathers identical operands and folds them with the same
-    deterministic tree).  Newer JAX renamed ``check_rep`` to
-    ``check_vma`` — try both so either version works.
+    deterministic tree).
     """
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - version-dependent
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axis_names):
-    """`jax.make_mesh` with explicit Auto axis types where supported."""
-    try:
-        return jax.make_mesh(
-            shape, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axis_names)
+    """`jax.make_mesh` with explicit Auto axis types."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

@@ -100,7 +100,7 @@ def _is_integral(aval) -> bool:
 _TRANSPARENT = {"reshape", "broadcast_in_dim", "squeeze", "transpose",
                 "slice", "rev", "copy"}
 # call-like primitives whose result is really produced by an inner jaxpr
-_CALL_LIKE = {"pjit", "closed_call", "core_call", "custom_jvp_call",
+_CALL_LIKE = {"jit", "pjit", "closed_call", "core_call", "custom_jvp_call",
               "custom_vjp_call", "custom_vjp_call_jaxpr", "remat"}
 
 
@@ -114,7 +114,7 @@ def _producer_map(level):
 
 def _effective_producer(level, var, depth: int = 0):
     """(level, eqn) that semantically produced ``var``, looking through
-    call-like wrappers (jnp.where traces as a pjit around select_n) and
+    call-like wrappers (jnp.where traces as a jit call around select_n) and
     pure data movement.  (None, None) when untraceable."""
     if depth > 16:
         return None, None

@@ -113,8 +113,8 @@ def latency_stats(latencies) -> Dict[str, float]:
       0.99·(n-1)+1), kept for the figures so existing plots don't move;
     * ``p99_nearest`` — the NEAREST-RANK definition (the smallest value
       with at least ``ceil(0.99·n)`` values ≤ it), computed by the SAME
-      `policy_core.nearest_rank_p99` f32 value bisection the kernel
-      runs on its in-VMEM merged latency block (DESIGN.md §14) — the
+      `policy_core.nearest_rank_p99` f32 value bisection the sweep
+      runs on its merged latency block (DESIGN.md §14) — the
       host-side number that matches ``MET_P99`` / ``SweepMerge.p99``
       bit-for-bit.  Nearest-rank is always an actual observed latency;
       linear interpolation generally is not, so the two differ whenever

@@ -167,9 +167,13 @@ def run_window(state: SchedState, work: Workload, key: jax.Array, *,
         rng0 = jnp.zeros((), jnp.uint32)
 
     # Process in plan order; emit (orig_index, chosen) pairs and unpermute.
-    obj = work.object_ids[plan.order]
-    lens = work.lengths[plan.order]
-    val = work.valid[plan.order]
+    # Policies that keep arrival order skip both permutations: they are
+    # identities, and the unpermute's scatter of an iota by the same iota
+    # aborts the TPU compiler's scatter fusion.
+    reorders = policy.name in P.REORDERING_POLICIES
+    obj, lens, val = work.object_ids, work.lengths, work.valid
+    if reorders:
+        obj, lens, val = obj[plan.order], lens[plan.order], val[plan.order]
     keys = jax.random.split(key, r)
 
     def body(carry, xs):
@@ -206,10 +210,14 @@ def run_window(state: SchedState, work: Workload, key: jax.Array, *,
     # inverse permutation apply (permute_from_sorted) computes the SAME
     # relocation (property-pinned in tests/test_policies.py), so the
     # backends stay bit-exact without sharing this code path.
-    inv = jnp.zeros((r,), jnp.int32).at[plan.order].set(pos)
-    chosen = chosen_sorted[inv]
-    redirected = redir_sorted[inv] & work.valid
-    latencies = lat_sorted[inv] * work.valid
+    if reorders:
+        inv = jnp.zeros((r,), jnp.int32).at[plan.order].set(pos)
+        chosen_sorted = chosen_sorted[inv]
+        redir_sorted = redir_sorted[inv]
+        lat_sorted = lat_sorted[inv]
+    chosen = chosen_sorted
+    redirected = redir_sorted & work.valid
+    latencies = lat_sorted * work.valid
     if req_to_step is not None:
         chosen = chosen[req_to_step]
         redirected = redirected[req_to_step] & orig_work.valid
@@ -485,8 +493,9 @@ def run_stream_jit(state, work, key, *, policy, log_cfg, window_size,
 
 
 class ClientMerge(NamedTuple):
-    """Per-trial cross-client aggregates fused in-VMEM by the 2-D
-    (trials × clients) grid kernel (DESIGN.md §11) — the per_client
+    """Per-trial cross-client aggregates of the 2-D (trials × clients)
+    grid kernel's streams (DESIGN.md §11, merged after the kernel by
+    `ops.sched_stream_grid`, §17) — the per_client
     contention model's "typical client" view, merged over REAL clients
     (a client is real iff its slice scheduled at least one valid
     request; phantom padded clients are masked out with the
@@ -494,9 +503,8 @@ class ClientMerge(NamedTuple):
 
     ``lats``/``lats_valid`` are the MERGED LATENCY BLOCK (DESIGN.md
     §14): every client's grouped-step latencies (masked to 0 where
-    invalid) and 0/1 validity, accumulated in VMEM across the client
-    grid steps.  With ``merge_mean=True`` the kernel has already
-    bisected the trial's cross-client nearest-rank p99 out of it into
+    invalid) and 0/1 validity.  With ``merge_mean=True`` the merge has
+    already bisected the trial's cross-client nearest-rank p99 out of it into
     ``metrics[:, MET_P99]``; with ``merge_mean=False`` (the sharded
     sweep) the lane is 0 and the raw block ships so
     `parallel.sweep.run_sweep` can all-gather it and bisect the GLOBAL

@@ -148,6 +148,10 @@ def _recursive_average_boundaries(sorted_len: jax.Array, valid: jax.Array,
     return policy_core.recursive_average_bounds(sorted_len, nvalid, n_levels)
 
 
+# policies whose window plan processes requests out of arrival order
+REORDERING_POLICIES = ("mlml", "nltr")
+
+
 def plan_window(cfg: PolicyConfig, state: SchedState, object_ids: jax.Array,
                 lengths: jax.Array, valid: jax.Array) -> WindowPlan:
     """Build the window-start plan (sorts + sections) for a policy.
@@ -166,7 +170,7 @@ def plan_window(cfg: PolicyConfig, state: SchedState, object_ids: jax.Array,
     # contract-ok: CC-SORT engine keeps backend argsort; kernel twin is rank_desc (§10)
     sorted_servers = jnp.argsort(-state.probs).astype(jnp.int32)
 
-    if cfg.name in ("mlml", "nltr"):
+    if cfg.name in REORDERING_POLICIES:
         # Requests processed in length-desc order; invalid (padding) rows sink
         # to the end via -inf keys.
         key_len = jnp.where(valid, lengths, -jnp.inf)

@@ -56,16 +56,15 @@ N_METRICS = 5
 MET_NAMES = ("makespan", "p99_lat", "lat_sum", "lat_max", "n_valid")
 MET_PAD = 128          # kernel metrics row padded to one f32 lane tile
 
-# Cross-client merged metrics (DESIGN.md §11/§14): the 2-D (trials ×
-# clients) grid kernel reduces its clients' per-stream metric rows into
-# one per-TRIAL row before the block retires — lanes [0, N_METRICS) keep
-# the MET_* meaning merged over REAL clients (makespan/lat_max by max,
-# lat_sum/n_valid through `masked_client_sum`; the p99 lane is the
-# nearest-rank p99 of the MERGED latency block the kernel accumulates in
-# VMEM across its client grid steps — `nearest_rank_p99` is layout- and
-# order-insensitive, so merging needs no association contract), plus the
-# real-client count.  `client_stream_metrics` below is the bit-exact
-# host/engine twin.
+# Cross-client merged metrics (DESIGN.md §11/§14/§17): the 2-D (trials ×
+# clients) grid's per-stream metric rows are merged into one per-TRIAL
+# row — lanes [0, N_METRICS) keep the MET_* meaning merged over REAL
+# clients (makespan/lat_max by max, lat_sum/n_valid through
+# `masked_client_sum`; the p99 lane is the nearest-rank p99 of the
+# MERGED latency block of all the trial's clients — `nearest_rank_p99`
+# is layout- and order-insensitive, so merging needs no association
+# contract), plus the real-client count.  `client_stream_metrics` below
+# computes it for both backends.
 MET_N_CLIENTS = 5
 N_CMETRICS = 6
 CMET_NAMES = MET_NAMES + ("n_clients",)
@@ -807,13 +806,11 @@ def masked_client_sum(x, client_valid, client_tile: int, xp=jnp):
     """Cross-client masked sum over the LEADING client axis with the 2-D
     grid's float association: sequential (ascending) over
     ``ceil(C / client_tile)`` client blocks, each block folded by
-    :func:`tree_sum`.  This mirrors exactly how the grid kernel
-    accumulates — within a program instance the ``client_tile`` client
-    sublanes fold through the halving tree, and the per-trial
-    accumulator adds one block per client grid step — so the jax path,
-    the oracle and the kernel produce bit-identical merged floats
-    (DESIGN.md §11).  ``client_valid``: (C,) bool — phantom clients
-    (padded slices that scheduled nothing) contribute exact zeros.
+    :func:`tree_sum` — one block per client tile of the 2-D grid — so
+    the jax path, the oracle and the kernel path produce bit-identical
+    merged floats (DESIGN.md §11).  ``client_valid``: (C,) bool —
+    phantom clients (padded slices that scheduled nothing) contribute
+    exact zeros.
     Returns ``x.shape[1:]``."""
     c = x.shape[0]
     xm = _mask_clients(x, client_valid, xp)
@@ -832,8 +829,8 @@ def masked_client_mean(x, client_valid, client_tile: int, xp=jnp):
     """Masked cross-client mean: :func:`masked_client_sum` divided by the
     REAL client count (at least 1) — the "typical client's view"
     aggregate of the per_client contention model, shared verbatim by
-    ``simulate``'s jax path and re-derived bit-identically by the grid
-    kernel's in-VMEM merge."""
+    ``simulate``'s jax path and the grid kernel's merge
+    (`ops.sched_stream_grid`)."""
     total = masked_client_sum(x, client_valid, client_tile, xp)
     dtype = total.dtype
     n_real = masked_client_sum(
@@ -850,8 +847,8 @@ def masked_client_max(x, client_valid, xp=jnp):
 
 def client_stream_metrics(metrics, client_valid, client_tile: int, xp=jnp,
                           merged_lats=None, merged_valid=None):
-    """Merge per-client stream-metric rows into the per-trial row the 2-D
-    grid kernel fuses in-VMEM (DESIGN.md §11/§14).  ``metrics``:
+    """Merge per-client stream-metric rows into the per-trial row of the
+    2-D grid (DESIGN.md §11/§14).  ``metrics``:
     (C, >= N_METRICS) per-client rows (:func:`stream_metrics` layout);
     ``client_valid``: (C,) bool.  Returns (N_CMETRICS,) f32 in ``MET_*``
     + ``MET_N_CLIENTS`` order.
@@ -861,8 +858,7 @@ def client_stream_metrics(metrics, client_valid, client_tile: int, xp=jnp,
     lane is :func:`nearest_rank_p99` over the flattened merged block
     (every reduction in it — counts of exact 0/1 floats, min/max — is
     order- and layout-insensitive, so ANY client/step ordering of the
-    same multiset gives identical bits; the kernel's VMEM accumulation
-    order needs no association contract, DESIGN.md §14).  When omitted
+    same multiset gives identical bits, DESIGN.md §14).  When omitted
     the lane is 0 — the pre-merged-block behaviour."""
     f32 = jnp.float32 if xp is jnp else np.float32
     metrics = metrics.astype(f32)

@@ -589,8 +589,8 @@ def _sched_trials(cfg: SimConfig, policy: PolicyConfig, log_cfg: LogConfig,
     (``window_size_eff`` in the result, warned about at dispatch), and
     every cross-client aggregate — window_loads mean, probe sum, phase
     makespan — masks phantom clients and merges with the
-    `policy_core.masked_client_sum` association, so the kernel's
-    in-VMEM merge is bit-identical to the jax path's.
+    `policy_core.masked_client_sum` association, so the kernel path's
+    merge is bit-identical to the jax path's.
 
     Returns ``(chosen, probes, redirected, latencies, wl, phase)`` in
     original request order; ``phase`` is None when no fused/folded
@@ -683,7 +683,7 @@ def _sched_trials(cfg: SimConfig, policy: PolicyConfig, log_cfg: LogConfig,
             phase = smerge.phase_time
             probes = smerge.probe_msgs
         elif merged is not None:
-            # the 2-D grid kernel's in-VMEM merge (bit-identical to the
+            # the 2-D grid kernel path's merge (bit-identical to the
             # jax branch below — asserted in tests/test_simulate.py)
             wl = merged.window_loads_mean
             phase = merged.metrics[:, policy_core.MET_MAKESPAN]
@@ -773,9 +773,10 @@ def run_trials(key: jax.Array, cfg: SimConfig, policy: PolicyConfig,
     `_run_batched` runner.  The kernel backend runs the WHOLE sweep as
     one pallas_call — grid = trial tiles for shared_log (DESIGN.md §9),
     ``(trial tiles, client tiles)`` for the per_client contention model
-    (DESIGN.md §11) — with per-trial makespan (and, under per_client,
-    the cross-client merges) fused in-VMEM; every §3.4 policy dispatches
-    through it since the in-VMEM sorts of DESIGN.md §10.  Decisions,
+    (DESIGN.md §11) — with per-trial makespan fused in-VMEM (under
+    per_client the cross-client merges follow the kernel, §17); every
+    §3.4 policy dispatches through it since the in-VMEM sorts of
+    DESIGN.md §10.  Decisions,
     latencies, loads, window_loads and phase_time are bit-exact vs.
     mapping the sequential kernel path trial by trial AND vs. the
     vmapped jax engine (asserted in tests/test_kernels.py and

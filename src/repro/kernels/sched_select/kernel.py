@@ -10,32 +10,43 @@ entire stream and emits one assignment per request, instead of bouncing
 the carry through XLA's while-loop machinery (HBM round trips per
 decision).
 
-TRIAL-GRID form (DESIGN.md §9): the whole Monte-Carlo sweep — T
-independent windowed `run_stream` traces — runs as ONE ``pallas_call``
-with ``grid = (T / t_tile,)``.  Each program instance owns a
-``t_tile``-trial slice of the packed ``(T, 4, M)`` log stack, the
-``(T, W, M)`` rate traces and the ``(T, N)`` request/latency blocks, and
-holds its trials' tables in one ``(4, t_tile, M_pad)`` VMEM scratch.
-Trials are INDEPENDENT streams, so the per-request decision loop
-vectorizes over the trial sublane axis: every op below acts on
-``(t_tile, M_pad)`` tiles — the native f32 ``(8, 128)`` TPU tile at the
-default ``t_tile = 8`` — and ``t_tile = 1`` degenerates to the original
-single-stream kernel bit-for-bit (same ops on ``(1, M_pad)`` rows).
-Grid = independent clients OR independent trials; there is no cross-
-stream gossip, exactly as in the paper §3.3.
+ONE ``pallas_call`` serves both forms.  Streams are laid out in
+BLOCKS: a program instance owns ``s_tile`` independent streams on the
+sublane axis, and every per-stream operand reaches the kernel as
+``(s_tile, ...)`` — the block's two leading grid axes are squeezed, so
+the (8, 128) block rule holds for ANY tile (the stream axis is always
+the block's full dimension).  Trials are INDEPENDENT streams, so the
+per-request decision loop vectorizes over the stream sublanes: every op
+below acts on ``(s_tile, M_pad)`` tiles — the native f32 ``(8, 128)``
+TPU tile at the default ``t_tile = 8`` — and one stream degenerates to
+the original single-stream kernel bit-for-bit.  There is no
+cross-stream gossip, exactly as in the paper §3.3.
 
-2-D (TRIALS × CLIENTS) GRID form (DESIGN.md §11): the per_client
-contention model — T trials, each partitioned over C private-log
-clients — runs as ONE ``pallas_call`` with ``grid = (T / t_tile,
-C / c_tile)``.  Per-stream operands carry both axes; the rate/drain
-traces stay per-TRIAL (a trial's clients share its cluster schedule)
-and broadcast over the client sublanes in-VMEM.  The decision loop is
-the SAME function — the block flattens to ``t_tile * c_tile`` stream
-sublanes — and before a block retires it folds its clients into
-per-trial cross-client aggregates (masked client-mean window loads,
-merged metric row with real-client count) accumulated across the
-client grid dimension with the `policy_core.masked_client_sum`
-association, so the jax path's merge is bit-identical.
+* TRIAL-GRID form (DESIGN.md §9, :func:`sched_stream_call`): the whole
+  Monte-Carlo sweep — T independent windowed `run_stream` traces — with
+  ``grid = (T / t_tile, 1)`` and ``s_tile = t_tile``.
+* 2-D (TRIALS × CLIENTS) GRID form (DESIGN.md §11,
+  :func:`sched_stream_grid_call`): the per_client contention model — T
+  trials, each partitioned over C private-log clients — with ``grid =
+  (T / t_tile, C / c_tile)`` and ``s_tile = t_tile * c_tile`` (stream
+  ``s`` of a block is trial ``s // c_tile``, client ``s % c_tile``).
+  The rate/drain traces stay per-TRIAL (a trial's clients share its
+  cluster schedule) and are broadcast over the client sublanes in-VMEM
+  by exact selects.  The cross-client merges are NOT in the kernel:
+  `ops.sched_stream_grid` folds the per-stream outputs with the
+  `policy_core` merge twins, the same code the jax backend runs.
+
+REQUEST LAYOUT (TPU lowering): the request/choice/latency streams are
+stored window by window, each window padded with inert ``valid = 0``
+lanes to ``ws_pad`` = the window rounded up to whole 128-lane vregs
+(:func:`window_lanes`).  Every window then starts at a lane offset that
+is a provable multiple of 128 — Mosaic refuses dynamic lane offsets it
+cannot prove aligned — and the kernel reads each window ONCE into
+registers, selects the request of step ``j`` with a one-hot masked sum
+(a single non-zero term: an exact relocation, no per-lane ref access),
+accumulates the window's choices and latencies the same way and writes
+them back once.  The loop still runs exactly ``window_size`` decisions:
+padding lanes are never scheduled and never advance the LCG.
 
 Per window the kernel snapshots the probability ranking (TRH's plan),
 loops the window's requests (selection → threshold guard → Eq. (1)-(3)
@@ -74,11 +85,12 @@ server ids) in sorted order as a single masked-sum permutation apply
 (no gather op; ``jnp.argsort`` does not lower inside a fused Pallas
 body, and its tie/tree behaviour is a backend choice).  The comparator
 is a strict total order, so the permutation equals the engine's stable
-``argsort`` bit-for-bit; nLTR's section bounds come from the shared
-`policy_core.recursive_average_bounds` on the natural-width sorted keys
-with `lane_sum`-associated means.  MLML/nLTR loop the window in sorted
-order via POSITION one-hots, accumulate decisions/latencies in sorted
-order, and unsort both with ONE vectorized
+``argsort`` bit-for-bit (the window's padding lanes rank after every
+real request, so positions ``[0, window_size)`` are unchanged); nLTR's
+section bounds come from the shared `policy_core.recursive_average_bounds`
+on the sorted keys with `lane_sum`-associated means.  MLML/nLTR loop the
+window in sorted order via POSITION one-hots, accumulate
+decisions/latencies in sorted order, and unsort both with ONE vectorized
 `policy_core.permute_from_sorted` apply per window; the fused metrics
 then reduce in ORIGINAL request order, matching
 `policy_core.stream_metrics` (maxima and the valid count are order-free
@@ -86,8 +98,8 @@ exact and collapse to vectorized masked reductions — only the latency
 sum keeps the host twin's sequential per-request float-add chain).
 
 FUSED METRICS (DESIGN.md §9): before a program instance retires, it
-reduces its trials' per-step latencies — still VMEM-resident — into a
-``(t_tile, MET_PAD)`` metrics row (makespan, nearest-rank p99 via f32
+reduces its streams' per-step latencies — still VMEM-resident — into a
+``(s_tile, MET_PAD)`` metrics row (makespan, nearest-rank p99 via f32
 value bisection, latency sum in request order, latency max, valid count;
 `policy_core.MET_*` layout), so the sweep's headline numbers never
 round-trip through HBM.  ``policy_core.stream_metrics`` is the bit-exact
@@ -107,15 +119,23 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.policy_core import (LCG_A, LCG_C, MET_LAT_MAX, MET_LAT_SUM,
-                                    MET_MAKESPAN, MET_N_CLIENTS, MET_N_VALID,
-                                    MET_P99, MET_PAD, N_ROWS,
-                                    P99_BISECT_ITERS, P99_Q, ROW_EST,
-                                    ROW_EWMA, ROW_LOADS, ROW_PROBS, lane_sum,
-                                    permute_from_sorted, permute_to_sorted,
-                                    rank_desc, recursive_average_bounds,
-                                    tree_sum, window_decrements)
+                                    MET_MAKESPAN, MET_N_VALID, MET_P99,
+                                    MET_PAD, N_ROWS, P99_BISECT_ITERS, P99_Q,
+                                    ROW_EST, ROW_EWMA, ROW_LOADS, ROW_PROBS,
+                                    lane_sum, permute_from_sorted,
+                                    permute_to_sorted, rank_desc,
+                                    recursive_average_bounds,
+                                    window_decrements)
 
 _BIG = 3.4e38  # padding-lane load: never selected, never drained
+LANES = 128    # lanes per TPU vreg: the alignment unit of a window
+
+
+def window_lanes(window_size: int) -> int:
+    """Lanes one window occupies in the kernel's request layout: the
+    window rounded up to whole vregs, so every window starts at a lane
+    offset Mosaic can prove to be a multiple of 128."""
+    return -(-window_size // LANES) * LANES
 
 
 def _lcg(rng):
@@ -127,112 +147,73 @@ def _lcg_mod(rng, n: int):
                        & jnp.int32(0x7FFFFFFF), n)
 
 
+def _argmin_first(x, lane):
+    """Index of the FIRST minimum along the last axis, ``(..., 1)`` int32
+    — `jnp.argmin`'s tie rule spelled out.  Ties are common (servers
+    drained to zero load score equal), and Mosaic's argmin reduction
+    does not promise the lowest index among equal minima."""
+    mn = jnp.min(x, axis=-1, keepdims=True)
+    return jnp.min(jnp.where(x == mn, lane, x.shape[-1]), axis=-1,
+                   keepdims=True)
+
+
 def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
                          rates_ref, dec_ref, choices_ref, lats_ref,
-                         final_table_ref, wloads_ref, metrics_ref, *rest,
-                         n_windows: int,
-                         window_size: int, n_servers: int, m_pad: int,
-                         t_tile: int, threshold: float, lam: float,
-                         alpha: float, window_dt: float, policy: str,
-                         observe: bool, renorm: bool, nltr_n: int,
-                         probe_choices: int, client_tile: int = 0,
-                         n_client_blocks: int = 1, merge_mean: bool = True,
+                         final_table_ref, wloads_ref, metrics_ref, tbl, *,
+                         n_windows: int, window_size: int, n_servers: int,
+                         m_pad: int, t_tile: int, client_tile: int,
+                         threshold: float, lam: float, alpha: float,
+                         window_dt: float, policy: str, observe: bool,
+                         renorm: bool, nltr_n: int, probe_choices: int,
                          ablate: int = 0):
     """One program instance of the stream kernel.
 
-    ``ablate`` (trial-grid form only) drops whole window phases for
-    DIFFERENTIAL per-phase profiling (DESIGN.md §16): 0 = the full
-    kernel; 1 = skip the fused metrics reduction; 2 = also skip the
-    per-request step loop; 3 = also skip the window-start sort/plan.
-    Levels are cumulative so every retained phase still sees the inputs
-    it would normally see.  Ablated outputs are NOT contract-bearing
-    (choices/latencies/metrics are zeros past the dropped phase) — the
-    levels exist only so `benchmarks/sched_perf.py` can attribute the
-    kernel's wall time phase by phase via timing differences.
+    Per-stream refs are ``(s_tile, ...)`` with ``s_tile = t_tile *
+    client_tile`` (``client_tile == 1`` is the trial-grid form); the
+    request refs are ``(s_tile, n_windows * ws_pad)`` in the padded
+    window layout; the per-trial rate/decrement refs are ``(t_tile,
+    n_windows, m_pad)``; ``tbl`` is the ``(N_ROWS, s_tile, m_pad)``
+    table scratch.
 
-    Trial-grid form (``client_tile == 0``): refs carry a leading
-    ``t_tile`` stream axis; ``rest`` is the ``(N_ROWS, t_tile, m_pad)``
-    table scratch.  2-D (trials × clients) grid form (DESIGN.md §11):
-    per-stream refs carry ``(t_tile, client_tile)`` leading axes, the
-    per-trial rate/decrement refs stay client-shared ``(t_tile, ...)``,
-    and ``rest`` is ``(cm_wloads_ref, cm_metrics_ref, cm_lats_ref,
-    cm_lval_ref, tbl)`` — the per-TRIAL cross-client accumulators
-    revisited across the client grid dimension (merged metric row,
-    window-load sums, and the MERGED LATENCY BLOCK of DESIGN.md §14:
-    each client grid step deposits its clients' masked grouped-step
-    latencies and validity into a ``(t_tile, C_pad, N)`` VMEM-resident
-    pair, so the last step can reduce the cross-client nearest-rank p99
-    while the whole merged block is still on-chip), plus the scratch.
-    The decision loop itself is identical: the ``t_tile * client_tile``
-    independent streams ride the sublane axis exactly like trials do in
-    the 1-D form."""
+    ``ablate`` drops whole window phases for DIFFERENTIAL per-phase
+    profiling (DESIGN.md §16): 0 = the full kernel; 1 = skip the fused
+    metrics reduction; 2 = also skip the per-request step loop; 3 =
+    also skip the window-start sort/plan.  Levels are cumulative so
+    every retained phase still sees the inputs it would normally see.
+    Ablated outputs are NOT contract-bearing (choices/latencies/metrics
+    are undefined past the dropped phase) — the levels exist only so
+    `benchmarks/sched_perf.py` can attribute the kernel's wall time
+    phase by phase via timing differences."""
     m = n_servers
-    grid_2d = client_tile > 0
-    if grid_2d and ablate:
-        raise ValueError("ablate profiling levels support the trial-grid "
-                         "(1-D) form only")
+    ws_pad = window_lanes(window_size)
+    s_tile = t_tile * client_tile
     do_metrics = ablate < 1
     do_steps = ablate < 2
     do_plan = ablate < 3
-    if grid_2d:
-        cm_wloads_ref, cm_metrics_ref, cm_lats_ref, cm_lval_ref, tbl = rest
-        s_tile = t_tile * client_tile
 
-        def req_read(ref, start, size):
-            return jnp.reshape(ref[:, :, pl.ds(start, size)], (s_tile, size))
+    def req_read(ref, w):
+        return ref[:, pl.ds(pl.multiple_of(w * ws_pad, LANES), ws_pad)]
 
-        def req_write(ref, start, val):
-            ref[:, :, pl.ds(start, val.shape[-1])] = jnp.reshape(
-                val, (t_tile, client_tile) + val.shape[1:])
+    def req_write(ref, w, val):
+        ref[:, pl.ds(pl.multiple_of(w * ws_pad, LANES), ws_pad)] = val
 
-        def trial_row(ref, w):
-            # (t_tile, m_pad) per-trial row, broadcast over the client
-            # sublanes (all of a trial's clients share its trace rates)
-            r = ref[:, pl.ds(w, 1), :][:, 0, :]
-            return jnp.reshape(jnp.broadcast_to(
-                r[:, None, :], (t_tile, client_tile, m_pad)),
-                (s_tile, m_pad))
+    def trial_row(ref, w):
+        """(s_tile, m_pad) per-stream row of a per-TRIAL operand."""
+        r = ref[:, pl.ds(w, 1), :][:, 0, :]             # (t_tile, m_pad)
+        if client_tile == 1:
+            return r
+        # stream s belongs to trial s // client_tile: broadcast each
+        # trial's row over its client sublanes with exact selects
+        sid = jax.lax.broadcasted_iota(jnp.int32, (s_tile, 1), 0)
+        out = jnp.broadcast_to(r[0:1], (s_tile, m_pad))
+        for t in range(1, t_tile):
+            out = jnp.where(sid >= t * client_tile, r[t:t + 1], out)
+        return out
 
-        def wl_write(ref, w, val):
-            ref[:, :, pl.ds(w, 1), :] = jnp.reshape(
-                val, (t_tile, client_tile, 1, m_pad))
-
-        def ftab_write(row, val):
-            final_table_ref[:, :, row, :] = jnp.reshape(
-                val, (t_tile, client_tile, m_pad))
-
-        def all_req(ref):
-            return jnp.reshape(ref[...], (s_tile, -1))
-
-        intab = jnp.reshape(table_ref[...], (s_tile, N_ROWS, m_pad))
-        seed0 = jnp.reshape(seed_ref[...], (s_tile, 1))
-    else:
-        (tbl,) = rest
-        s_tile = t_tile
-
-        def req_read(ref, start, size):
-            return ref[:, pl.ds(start, size)]
-
-        def req_write(ref, start, val):
-            ref[:, pl.ds(start, val.shape[-1])] = val
-
-        def trial_row(ref, w):
-            return ref[:, pl.ds(w, 1), :][:, 0, :]
-
-        def wl_write(ref, w, val):
-            ref[:, pl.ds(w, 1), :] = val[:, None, :]
-
-        def ftab_write(row, val):
-            final_table_ref[:, row, :] = val
-
-        def all_req(ref):
-            return ref[...]
-
-        intab = table_ref[...]                  # (t_tile, 4, m_pad)
-        seed0 = seed_ref[...]                   # (t_tile, 1)
-
+    intab = table_ref[...]                      # (s_tile, 4, m_pad)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, m_pad), 1)
     lv = lane < m                               # valid (non-padding) lanes
+    ws_lane = jax.lax.broadcasted_iota(jnp.int32, (1, ws_pad), 1)
 
     # --- pin the packed log stack in VMEM scratch --------------------------
     # tbl is (N_ROWS, s_tile, m_pad): tbl[row] is this tile's streams' row,
@@ -243,13 +224,17 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
     tbl[ROW_EST] = jnp.where(lv, intab[:, ROW_EST, :], 1.0)
 
     def pick(rows, onehot):
-        """Extract rows[onehot] per trial without gather (masked sum)."""
+        """Extract rows[onehot] per stream without gather (masked sum)."""
         return jnp.sum(jnp.where(onehot, rows, 0.0), axis=-1, keepdims=True)
 
     def window_body(w, carry):
         rng, mk, lsum, lmax, nval = carry
         cur_rates = jnp.where(lv, trial_row(rates_ref, w), 1.0)
         sort_policy = policy in ("mlml", "nltr")
+        # the window's requests, read once: (s_tile, ws_pad) each
+        obj_w = req_read(objs_ref, w)
+        len_w = req_read(lens_ref, w)
+        val_w = req_read(valid_ref, w) != 0
 
         if policy in ("trh", "mlml", "nltr") and do_plan:
             # Window-start plan (DESIGN.md §13): rank servers by
@@ -274,31 +259,27 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
             # MLML/nLTR process the window's requests in length-desc
             # order (DESIGN.md §13): rank the request block with one
             # all-pairs comparison, land obj/len/valid in sorted order
-            # with one permutation apply, and loop over POSITIONS — the
-            # per-step one-hot selects by position, no gathered order
-            # value and no per-step ref reads.
-            start = w * window_size
-            obj_w = req_read(objs_ref, start, window_size)   # (s, ws)
-            len_w = req_read(lens_ref, start, window_size)
-            val_w = req_read(valid_ref, start, window_size) != 0
+            # with one permutation apply, and loop over POSITIONS.
             rank_req, mkeys = rank_desc(len_w, valid=val_w)
             obj_s, len_s, val_s = permute_to_sorted(
                 rank_req, (obj_w, len_w, val_w.astype(jnp.int32)))
-            ws_lane = jax.lax.broadcasted_iota(jnp.int32, (1, window_size), 1)
             if policy == "nltr":
                 nvalid = jnp.sum(val_w.astype(jnp.int32), axis=-1,
                                  keepdims=True)
                 # sorted keys (-inf at invalid) for the section bounds;
-                # natural width — lane_sum's zero-padded halving tree is
-                # width-independent, so the bounds match the engine's
+                # lane_sum's zero-padded halving tree is width-
+                # independent, so the bounds match the engine's
                 skeys = permute_to_sorted(rank_req, (mkeys,))[0]
                 bounds = recursive_average_bounds(skeys, nvalid, nltr_n)
                 sec_size = max(m // 2 ** nltr_n, 1)
                 n_sections = 2 ** nltr_n
+        else:
+            # processing order == request order
+            obj_s, len_s, val_s = obj_w, len_w, val_w.astype(jnp.int32)
 
         def schedule_one(j, obj, ln, v, rng):
             """Selection + guard + Eq. (1)-(3)/feedback for one request per
-            trial; mutates the VMEM table, returns (choose, lat, latv,
+            stream; mutates the VMEM table, returns (choose, lat, latv,
             rng).  ``j`` is the PROCESSING position in the window (==
             request position except for the sorted policies)."""
             loads = tbl[ROW_LOADS]
@@ -310,12 +291,9 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
             if policy == "rr":
                 target = default
             elif policy == "minload":
-                target = jnp.argmin(loads, axis=-1,
-                                    keepdims=True).astype(jnp.int32)
+                target = _argmin_first(loads, lane)
             elif policy == "ect":
-                scores = (loads + ln) / est
-                target = jnp.argmin(scores, axis=-1,
-                                    keepdims=True).astype(jnp.int32)
+                target = _argmin_first((loads + ln) / est, lane)
             elif policy == "mlml":
                 # j-th longest request -> j-th lightest server (Alg. 1)
                 target = server_at(jnp.reshape(jax.lax.rem(j, m), (1, 1)))
@@ -380,7 +358,7 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
                                    default).astype(jnp.int32)
 
             # -- Eq. (1)-(3) one-hot updates (masked on padding rows) ------
-            onehot = lane == choose                          # (t, m_pad)
+            onehot = lane == choose                          # (s, m_pad)
             upd = onehot & v
             new_loads = jnp.where(upd, loads + ln, loads)    # Eq. (1)
             tbl[ROW_LOADS] = new_loads
@@ -421,9 +399,9 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
             # but schedules nothing — the timing delta vs level 1 is the
             # step loop's cost.
             carry = (rng, mk, lsum, lmax, nval)
-        elif sort_policy:
-            def sorted_req_body(j, carry):
-                rng, ch_acc, lat_acc = carry
+        else:
+            def req_body(j, carry):
+                rng, ch_acc, lat_acc, mk, lsum, lmax, nval = carry
                 sel = ws_lane == j              # PROCESSING position j
                 obj = jnp.sum(jnp.where(sel, obj_s, 0), axis=-1,
                               keepdims=True)
@@ -432,64 +410,52 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
                 v = jnp.sum(jnp.where(sel, val_s, 0), axis=-1,
                             keepdims=True) != 0
                 choose, lat, latv, rng = schedule_one(j, obj, ln, v, rng)
-                # accumulate in SORTED order; ONE inverse apply at the
-                # window close moves everything back at once (§13)
                 ch_acc = jnp.where(sel, choose, ch_acc)
                 lat_acc = jnp.where(sel, latv, lat_acc)
-                return rng, ch_acc, lat_acc
+                if not sort_policy:
+                    # fused metric accumulators (stream_metrics twin)
+                    mk = jnp.where(v, jnp.maximum(mk, wopen + lat), mk)
+                    lsum = lsum + latv
+                    lmax = jnp.maximum(lmax, latv)
+                    nval = nval + jnp.where(v, 1.0, 0.0)
+                return rng, ch_acc, lat_acc, mk, lsum, lmax, nval
 
-            rng, ch_acc, lat_acc = jax.lax.fori_loop(
-                0, window_size, sorted_req_body,
-                (rng, jnp.zeros((s_tile, window_size), jnp.int32),
-                 jnp.zeros((s_tile, window_size), jnp.float32)),
+            rng, ch_acc, lat_acc, mk, lsum, lmax, nval = jax.lax.fori_loop(
+                0, window_size, req_body,
+                (rng, jnp.zeros((s_tile, ws_pad), jnp.int32),
+                 jnp.zeros((s_tile, ws_pad), jnp.float32),
+                 mk, lsum, lmax, nval),
                 unroll=False)
-            ch_req, lat_req = permute_from_sorted(rank_req,
-                                                  (ch_acc, lat_acc))
-            req_write(choices_ref, start, ch_req)
-            req_write(lats_ref, start, lat_req)
+            if sort_policy:
+                # decisions accumulated in SORTED order; ONE inverse
+                # apply moves everything back at once (§13)
+                ch_acc, lat_acc = permute_from_sorted(rank_req,
+                                                      (ch_acc, lat_acc))
+                # fused metrics in ORIGINAL request order (stream_metrics
+                # twin).  makespan/lat_max are exact order-free f32
+                # maxima and the valid count is integer-exact under any
+                # summation tree, so they collapse to vectorized masked
+                # reductions; ONLY the latency sum keeps the host twin's
+                # sequential per-request float-add chain (f32 adds do
+                # not reassociate).
+                mk = jnp.maximum(mk, jnp.max(
+                    jnp.where(val_w, wopen + lat_acc, -_BIG), axis=-1,
+                    keepdims=True))
+                lmax = jnp.maximum(lmax, jnp.max(lat_acc, axis=-1,
+                                                 keepdims=True))
+                nval = nval + jnp.sum(jnp.where(val_w, 1.0, 0.0), axis=-1,
+                                      keepdims=True)
 
-            # fused metrics in ORIGINAL request order (stream_metrics
-            # twin).  makespan/lat_max are exact order-free f32 maxima
-            # and the valid count is integer-exact under any summation
-            # tree, so they collapse to vectorized masked reductions;
-            # ONLY the latency sum keeps the host twin's sequential
-            # per-request float-add chain (f32 adds do not reassociate).
-            mk = jnp.maximum(mk, jnp.max(
-                jnp.where(val_w, wopen + lat_req, -_BIG), axis=-1,
-                keepdims=True))
-            lmax = jnp.maximum(lmax, jnp.max(lat_req, axis=-1,
-                                             keepdims=True))
-            nval = nval + jnp.sum(jnp.where(val_w, 1.0, 0.0), axis=-1,
-                                  keepdims=True)
+                def lsum_body(j, acc):
+                    return acc + jnp.sum(
+                        jnp.where(ws_lane == j, lat_acc, 0.0), axis=-1,
+                        keepdims=True)
 
-            def lsum_body(j, acc):
-                return acc + jnp.sum(jnp.where(ws_lane == j, lat_req, 0.0),
-                                     axis=-1, keepdims=True)
-
-            lsum = jax.lax.fori_loop(0, window_size, lsum_body, lsum,
-                                     unroll=False)
+                lsum = jax.lax.fori_loop(0, window_size, lsum_body, lsum,
+                                         unroll=False)
+            req_write(choices_ref, w, ch_acc)
+            req_write(lats_ref, w, lat_acc)
             carry = (rng, mk, lsum, lmax, nval)
-        else:
-            def req_body(j, carry):
-                rng, mk, lsum, lmax, nval = carry
-                i = w * window_size + j
-                obj = req_read(objs_ref, i, 1)               # (s, 1)
-                ln = req_read(lens_ref, i, 1)
-                v = req_read(valid_ref, i, 1) != 0
-                choose, lat, latv, rng = schedule_one(j, obj, ln, v, rng)
-                req_write(choices_ref, i, choose)
-                req_write(lats_ref, i, latv)
-                # -- fused metric accumulators (stream_metrics twin) -------
-                mk = jnp.where(v, jnp.maximum(mk, wopen + lat), mk)
-                lsum = lsum + latv
-                lmax = jnp.maximum(lmax, latv)
-                nval = nval + jnp.where(v, 1.0, 0.0)
-                return rng, mk, lsum, lmax, nval
-
-            carry = jax.lax.fori_loop(0, window_size, req_body,
-                                      (rng, mk, lsum, lmax, nval),
-                                      unroll=False)
-            rng = carry[0]
 
         # -- window close: renormalize probs, drain queues (advance_time) --
         if renorm:
@@ -507,174 +473,157 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
             dec = jnp.where(lv, trial_row(dec_ref, w), 0.0)
             drained = jnp.maximum(loads - dec, 0.0)
             tbl[ROW_LOADS] = jnp.where(lv, drained, _BIG)
-        wl_write(wloads_ref, w, jnp.where(lv, tbl[ROW_LOADS], 0.0))
+        wloads_ref[:, pl.ds(w, 1), :] = jnp.where(
+            lv, tbl[ROW_LOADS], 0.0)[:, None, :]
         return carry
 
-    seed = seed0.astype(jnp.uint32)                          # (s, 1)
+    seed = seed_ref[...].astype(jnp.uint32)                  # (s, 1)
     zero = jnp.zeros((s_tile, 1), jnp.float32)
     _, mk, lsum, lmax, nval = jax.lax.fori_loop(
         0, n_windows, window_body, (seed, zero, zero, zero, zero),
         unroll=False)
     zero_pad = jnp.broadcast_to(~lv, (s_tile, m_pad))
     for row in range(N_ROWS):
-        ftab_write(row, jnp.where(zero_pad, 0.0, tbl[row]))
+        final_table_ref[:, row, :] = jnp.where(zero_pad, 0.0, tbl[row])
 
     # -- fused metrics: reduce the VMEM-resident latency block -------------
     # (policy_core.stream_metrics / nearest_rank_p99 are the bit-exact
-    # host twins — keep the float ops in lockstep with them.)
-    lats_all = all_req(lats_ref)                             # (s, N)
-    val_all = all_req(valid_ref) != 0
-    mlane = jax.lax.broadcasted_iota(jnp.int32, (1, MET_PAD), 1)
-    if do_metrics:
-        k = jnp.ceil(jnp.float32(P99_Q) * nval)
-        lo = jnp.full((s_tile, 1), -1.0, jnp.float32)
-        hi = lmax
-
-        def bisect(_, lo_hi):
-            lo, hi = lo_hi
-            mid = jnp.float32(0.5) * (lo + hi)
-            cnt = jnp.sum(jnp.where(val_all & (lats_all <= mid), 1.0, 0.0),
-                          axis=-1, keepdims=True)
-            go_hi = cnt >= k
-            return jnp.where(go_hi, lo, mid), jnp.where(go_hi, mid, hi)
-
-        lo, _ = jax.lax.fori_loop(0, P99_BISECT_ITERS, bisect, (lo, hi))
-        p99 = jnp.min(jnp.where(val_all & (lats_all > lo), lats_all, _BIG),
-                      axis=-1, keepdims=True)
-        p99 = jnp.where(nval > 0, p99, 0.0)
-        met_row = (jnp.where(mlane == MET_MAKESPAN, mk, 0.0)
-                   + jnp.where(mlane == MET_P99, p99, 0.0)
-                   + jnp.where(mlane == MET_LAT_SUM, lsum, 0.0)
-                   + jnp.where(mlane == MET_LAT_MAX, lmax, 0.0)
-                   + jnp.where(mlane == MET_N_VALID, nval, 0.0))
-    else:
-        met_row = jnp.zeros((s_tile, MET_PAD), jnp.float32)
-    if not grid_2d:
-        metrics_ref[...] = met_row
+    # host twins — keep the float ops in lockstep with them.  The
+    # window padding lanes are invalid with zero latency, so they drop
+    # out of every reduction.)
+    if not do_metrics:
+        metrics_ref[...] = jnp.zeros((s_tile, MET_PAD), jnp.float32)
         return
-    metrics_ref[...] = jnp.reshape(met_row,
-                                   (t_tile, client_tile, MET_PAD))
+    lats_all = lats_ref[...]                                 # (s, N_pad)
+    val_all = valid_ref[...] != 0
+    mlane = jax.lax.broadcasted_iota(jnp.int32, (1, MET_PAD), 1)
+    k = jnp.ceil(jnp.float32(P99_Q) * nval)
+    lo = jnp.full((s_tile, 1), -1.0, jnp.float32)
+    hi = lmax
 
-    # -- cross-client merge (2-D grid, DESIGN.md §11) ----------------------
-    # Fold this block's client_tile client sublanes into per-TRIAL
-    # aggregates while everything is VMEM-resident, then accumulate into
-    # the (t_tile, ...) merge outputs revisited across the client grid
-    # dimension: within-block sums run the policy_core.tree_sum halving
-    # tree and blocks add SEQUENTIALLY in ascending client order — the
-    # exact association of policy_core.masked_client_sum, so the jax
-    # path's merge is bit-identical.  A client is REAL iff it scheduled
-    # at least one valid step (nval > 0 ⇔ any(valid) — phantom padded
-    # clients contribute exact zeros everywhere).
-    j = pl.program_id(1)
-    mk_c = jnp.reshape(mk, (t_tile, client_tile))
-    lsum_c = jnp.reshape(lsum, (t_tile, client_tile))
-    lmax_c = jnp.reshape(lmax, (t_tile, client_tile))
-    nval_c = jnp.reshape(nval, (t_tile, client_tile))
-    cvalid = nval_c > 0.0
+    def bisect(_, lo_hi):
+        lo, hi = lo_hi
+        mid = jnp.float32(0.5) * (lo + hi)
+        cnt = jnp.sum(jnp.where(val_all & (lats_all <= mid), 1.0, 0.0),
+                      axis=-1, keepdims=True)
+        go_hi = cnt >= k
+        return jnp.where(go_hi, lo, mid), jnp.where(go_hi, mid, hi)
 
-    def csum(x):
-        return tree_sum(jnp.where(cvalid, x, 0.0), axis=1)[:, 0:1]
+    lo, _ = jax.lax.fori_loop(0, P99_BISECT_ITERS, bisect, (lo, hi))
+    p99 = jnp.min(jnp.where(val_all & (lats_all > lo), lats_all, _BIG),
+                  axis=-1, keepdims=True)
+    p99 = jnp.where(nval > 0, p99, 0.0)
+    metrics_ref[...] = (jnp.where(mlane == MET_MAKESPAN, mk, 0.0)
+                        + jnp.where(mlane == MET_P99, p99, 0.0)
+                        + jnp.where(mlane == MET_LAT_SUM, lsum, 0.0)
+                        + jnp.where(mlane == MET_LAT_MAX, lmax, 0.0)
+                        + jnp.where(mlane == MET_N_VALID, nval, 0.0))
 
-    def cmax(x):
-        return jnp.max(jnp.where(cvalid, x, 0.0), axis=1, keepdims=True)
 
-    blk_row = (jnp.where(mlane == MET_MAKESPAN, cmax(mk_c), 0.0)
-               + jnp.where(mlane == MET_LAT_SUM, csum(lsum_c), 0.0)
-               + jnp.where(mlane == MET_LAT_MAX, cmax(lmax_c), 0.0)
-               + jnp.where(mlane == MET_N_VALID, csum(nval_c), 0.0)
-               + jnp.where(mlane == MET_N_CLIENTS,
-                           csum(jnp.ones_like(nval_c)), 0.0))
-    wl_c = jnp.reshape(wloads_ref[...],
-                       (t_tile, client_tile, n_windows, m_pad))
-    blk_wl = tree_sum(jnp.where(cvalid[:, :, None, None], wl_c, 0.0),
-                      axis=1)[:, 0]                    # (t, n_win, m_pad)
-    is_max_lane = (mlane == MET_MAKESPAN) | (mlane == MET_LAT_MAX)
+def _to_window_layout(x, n_win: int, window_size: int):
+    """(..., n_win * window_size) -> (..., n_win * ws_pad): each window
+    padded with zero (= invalid, inert) lanes to whole vregs."""
+    ws_pad = window_lanes(window_size)
+    if ws_pad == window_size:
+        return x
+    lead = x.shape[:-1]
+    x = x.reshape(lead + (n_win, window_size))
+    x = jnp.pad(x, [(0, 0)] * (len(lead) + 1)
+                + [(0, ws_pad - window_size)])
+    return x.reshape(lead + (n_win * ws_pad,))
 
-    @pl.when(j == 0)
-    def _init_merge():
-        cm_wloads_ref[...] = blk_wl
-        cm_metrics_ref[...] = blk_row
 
-    @pl.when(j > 0)
-    def _acc_merge():
-        cm_wloads_ref[...] = cm_wloads_ref[...] + blk_wl
-        prev = cm_metrics_ref[...]
-        cm_metrics_ref[...] = jnp.where(is_max_lane,
-                                        jnp.maximum(prev, blk_row),
-                                        prev + blk_row)
+def _from_window_layout(x, n_win: int, window_size: int):
+    """Inverse of :func:`_to_window_layout` (drops the padding lanes)."""
+    ws_pad = window_lanes(window_size)
+    if ws_pad == window_size:
+        return x
+    lead = x.shape[:-1]
+    x = x.reshape(lead + (n_win, ws_pad))[..., :window_size]
+    return x.reshape(lead + (n_win * window_size,))
 
-    # -- merged latency block (DESIGN.md §14): deposit this block's
-    # clients' masked grouped-step latencies into the per-TRIAL
-    # (t_tile, C_pad, N) accumulator pair.  Each client grid step owns a
-    # disjoint client slice, so every column is written exactly once per
-    # trial row — no init/accumulate split needed.  Values are masked to
-    # 0 where invalid (phantom clients are all-invalid, so they deposit
-    # exact zeros) with the validity shipped alongside as 0/1 f32.
-    n_req = n_windows * window_size
-    lat_blk = jnp.reshape(jnp.where(val_all, lats_all, 0.0),
-                          (t_tile, client_tile, n_req))
-    val_blk = jnp.reshape(jnp.where(val_all, 1.0, 0.0),
-                          (t_tile, client_tile, n_req))
-    cm_lats_ref[:, pl.ds(j * client_tile, client_tile), :] = lat_blk
-    cm_lval_ref[:, pl.ds(j * client_tile, client_tile), :] = val_blk
 
-    if merge_mean:
-        @pl.when(j == n_client_blocks - 1)
-        def _finish_merge():
-            # masked client-MEAN of the window loads: divide the
-            # accumulated sum by the real-client count (>= 1) —
-            # masked_client_mean's twin.  ``merge_mean=False`` skips the
-            # divide and ships the raw masked client SUM instead: a mean
-            # is not composable across devices, so the sharded sweep
-            # (DESIGN.md §12) psum_tree's these per-device sum blocks and
-            # divides once, globally.
-            row = cm_metrics_ref[...]
-            n_real = jnp.sum(jnp.where(mlane == MET_N_CLIENTS, row, 0.0),
-                             axis=-1, keepdims=True)      # (t_tile, 1)
-            denom = jnp.maximum(n_real, 1.0)[:, :, None]  # (t_tile, 1, 1)
-            cm_wloads_ref[...] = cm_wloads_ref[...] / denom
+def _to_blocks(x, tt: int, ct: int):
+    """(T, C, ...) -> (T/tt, C/ct, tt*ct, ...): the streams of each
+    (trial tile, client tile) block made contiguous, trial-major."""
+    t, c = x.shape[:2]
+    rest = x.shape[2:]
+    x = x.reshape((t // tt, tt, c // ct, ct) + rest)
+    x = jnp.moveaxis(x, 2, 1)
+    return x.reshape((t // tt, c // ct, tt * ct) + rest)
 
-        @pl.when(j == n_client_blocks - 1)
-        def _finish_p99():
-            # cross-client merged nearest-rank p99 (DESIGN.md §14): the
-            # whole merged latency block is VMEM-resident now — run the
-            # SAME f32 value bisection as the per-stream fused metrics
-            # over the flattened (C_pad * N) merged lanes and land the
-            # result in the one cm_metrics lane the client-step
-            # accumulation left at 0.  Every reduction here (counts of
-            # exact 0/1 floats, min/max) is order- and layout-
-            # insensitive, so this matches `policy_core.nearest_rank_p99`
-            # on the host's merged block bit-for-bit regardless of how
-            # the clients were deposited.  ``merge_mean=False`` (the
-            # sharded sweep) skips it — a local p99 is not composable
-            # across devices; the sweep gathers the raw blocks and
-            # bisects once, globally (parallel/sweep.py).
-            c_pad = n_client_blocks * client_tile
-            lats_m = jnp.reshape(cm_lats_ref[...], (t_tile, c_pad * n_req))
-            lv_m = jnp.reshape(cm_lval_ref[...],
-                               (t_tile, c_pad * n_req)) != 0.0
-            nval_m = jnp.sum(jnp.where(lv_m, 1.0, 0.0),
-                             axis=-1, keepdims=True)
-            k_m = jnp.ceil(jnp.float32(P99_Q) * nval_m)
-            lo_m = jnp.full((t_tile, 1), -1.0, jnp.float32)
-            hi_m = jnp.max(jnp.where(lv_m, lats_m, 0.0),
-                           axis=-1, keepdims=True)
 
-            def bisect_m(_, lo_hi):
-                lo, hi = lo_hi
-                mid = jnp.float32(0.5) * (lo + hi)
-                cnt = jnp.sum(jnp.where(lv_m & (lats_m <= mid), 1.0, 0.0),
-                              axis=-1, keepdims=True)
-                go_hi = cnt >= k_m
-                return jnp.where(go_hi, lo, mid), jnp.where(go_hi, mid, hi)
+def _from_blocks(x, tt: int, ct: int):
+    """Inverse of :func:`_to_blocks`."""
+    nt, nc = x.shape[:2]
+    rest = x.shape[3:]
+    x = x.reshape((nt, nc, tt, ct) + rest)
+    x = jnp.moveaxis(x, 1, 2)
+    return x.reshape((nt * tt, nc * ct) + rest)
 
-            lo_m, _ = jax.lax.fori_loop(0, P99_BISECT_ITERS, bisect_m,
-                                        (lo_m, hi_m))
-            p99_m = jnp.min(jnp.where(lv_m & (lats_m > lo_m), lats_m, _BIG),
-                            axis=-1, keepdims=True)
-            p99_m = jnp.where(nval_m > 0, p99_m, 0.0)
-            cm_metrics_ref[...] = (cm_metrics_ref[...]
-                                   + jnp.where(mlane == MET_P99, p99_m, 0.0))
+
+def _stream_blocks_call(object_ids, lengths, valid, tables, seeds, win_rates,
+                        *, trial_tile: int, client_tile: int,
+                        window_size: int, interpret: bool, **kw):
+    """The ONE ``pallas_call``: ``(T, C, ...)`` per-stream operands,
+    ``(T, W, M_pad)`` per-trial rates, grid ``(T / tt, C / ct)``.
+    Returns the per-stream (choices, latencies, final_tables,
+    window_loads, metrics), each ``(T, C, ...)``."""
+    t, c, n = object_ids.shape
+    m_pad = tables.shape[-1]
+    n_win = win_rates.shape[1]
+    if n != n_win * window_size:
+        raise ValueError(f"stream length {n} != {n_win} windows x "
+                         f"{window_size}")
+    tt, ct = trial_tile, client_tile
+    if t % tt or c % ct:
+        raise ValueError(f"(T, C)=({t}, {c}) must be multiples of the "
+                         f"tiles ({tt}, {ct})")
+    s = tt * ct
+    n_lanes = n_win * window_lanes(window_size)
+    # drain decrements: pre-multiplied OUTSIDE the kernel (§9 FMA note)
+    win_dec = window_decrements(win_rates, kw["window_dt"]).astype(
+        jnp.float32)
+
+    def streams(x):
+        return _to_blocks(x, tt, ct)
+
+    def stream_spec(*shape):
+        zeros = (0,) * (1 + len(shape))
+        return pl.BlockSpec((pl.Squeezed(), pl.Squeezed(), s) + shape,
+                            lambda i, j: (i, j) + zeros)
+
+    trial_spec = pl.BlockSpec((tt, n_win, m_pad), lambda i, j: (i, 0, 0))
+    req = [streams(_to_window_layout(a, n_win, window_size))
+           for a in (object_ids, lengths, valid)]
+    kernel = functools.partial(
+        _sched_stream_kernel, n_windows=n_win, window_size=window_size,
+        m_pad=m_pad, t_tile=tt, client_tile=ct, **kw)
+    blocks = (t // tt, c // ct, s)
+    outs = pl.pallas_call(
+        kernel,
+        grid=(t // tt, c // ct),
+        in_specs=[stream_spec(n_lanes)] * 3 + [
+            stream_spec(N_ROWS, m_pad), stream_spec(1), trial_spec,
+            trial_spec],
+        out_specs=[stream_spec(n_lanes), stream_spec(n_lanes),
+                   stream_spec(N_ROWS, m_pad), stream_spec(n_win, m_pad),
+                   stream_spec(MET_PAD)],
+        out_shape=[
+            jax.ShapeDtypeStruct(blocks + (n_lanes,), jnp.int32),
+            jax.ShapeDtypeStruct(blocks + (n_lanes,), jnp.float32),
+            jax.ShapeDtypeStruct(blocks + (N_ROWS, m_pad), jnp.float32),
+            jax.ShapeDtypeStruct(blocks + (n_win, m_pad), jnp.float32),
+            jax.ShapeDtypeStruct(blocks + (MET_PAD,), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((N_ROWS, s, m_pad), jnp.float32)],
+        interpret=interpret,
+    )(*req, streams(tables), streams(seeds[..., None]), win_rates, win_dec)
+    choices, lats, ftab, wloads, metrics = (_from_blocks(o, tt, ct)
+                                            for o in outs)
+    return (_from_window_layout(choices, n_win, window_size),
+            _from_window_layout(lats, n_win, window_size),
+            ftab, wloads, metrics)
 
 
 def sched_stream_call(object_ids: jax.Array, lengths: jax.Array,
@@ -689,7 +638,7 @@ def sched_stream_call(object_ids: jax.Array, lengths: jax.Array,
 
     ``ablate`` drops trailing window phases for differential profiling
     (see `_sched_stream_kernel`); outputs past the dropped phase are
-    zeros, so nonzero levels are for timing only.
+    undefined, so nonzero levels are for timing only.
 
     object_ids/lengths/valid: (T, N) with N = W * window_size;
     tables: (T, 4, M_pad) packed log tensors; seeds: (T, 1) uint32;
@@ -701,51 +650,15 @@ def sched_stream_call(object_ids: jax.Array, lengths: jax.Array,
     final_tables (T, 4, M_pad) f32, window_loads (T, W, M_pad) f32,
     metrics (T, MET_PAD) f32 in `policy_core.MET_*` lane order).
     """
-    t, n = object_ids.shape
-    m_pad = tables.shape[-1]
-    n_win = win_rates.shape[1]
-    assert n == n_win * window_size, (n, n_win, window_size)
-    assert t % trial_tile == 0, (t, trial_tile)
-    tt = trial_tile
-    # drain decrements: pre-multiplied OUTSIDE the kernel (§9 FMA note)
-    win_dec = window_decrements(win_rates, window_dt).astype(jnp.float32)
-    kernel = functools.partial(
-        _sched_stream_kernel, n_windows=n_win, window_size=window_size,
-        n_servers=n_servers, m_pad=m_pad, t_tile=tt, threshold=threshold,
+    outs = _stream_blocks_call(
+        object_ids[:, None], lengths[:, None], valid[:, None],
+        tables[:, None], seeds.reshape(-1, 1), win_rates,
+        trial_tile=trial_tile, client_tile=1, window_size=window_size,
+        interpret=interpret, n_servers=n_servers, threshold=threshold,
         lam=lam, alpha=alpha, window_dt=window_dt, policy=policy,
         observe=observe, renorm=renorm, nltr_n=nltr_n,
         probe_choices=probe_choices, ablate=ablate)
-    return pl.pallas_call(
-        kernel,
-        grid=(t // tt,),
-        in_specs=[
-            pl.BlockSpec((tt, n), lambda i: (i, 0)),
-            pl.BlockSpec((tt, n), lambda i: (i, 0)),
-            pl.BlockSpec((tt, n), lambda i: (i, 0)),
-            pl.BlockSpec((tt, N_ROWS, m_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tt, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tt, n_win, m_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tt, n_win, m_pad), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tt, n), lambda i: (i, 0)),
-            pl.BlockSpec((tt, n), lambda i: (i, 0)),
-            pl.BlockSpec((tt, N_ROWS, m_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tt, n_win, m_pad), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tt, MET_PAD), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((t, n), jnp.int32),
-            jax.ShapeDtypeStruct((t, n), jnp.float32),
-            jax.ShapeDtypeStruct((t, N_ROWS, m_pad), jnp.float32),
-            jax.ShapeDtypeStruct((t, n_win, m_pad), jnp.float32),
-            jax.ShapeDtypeStruct((t, MET_PAD), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((N_ROWS, tt, m_pad), jnp.float32),   # the log stack
-        ],
-        interpret=interpret,
-    )(object_ids, lengths, valid, tables, seeds, win_rates, win_dec)
+    return tuple(o[:, 0] for o in outs)
 
 
 def sched_stream_grid_call(object_ids: jax.Array, lengths: jax.Array,
@@ -756,7 +669,6 @@ def sched_stream_grid_call(object_ids: jax.Array, lengths: jax.Array,
                            policy: str, observe: bool, renorm: bool,
                            trial_tile: int = 1, client_tile: int = 1,
                            nltr_n: int = 2, probe_choices: int = 2,
-                           merge_mean: bool = True,
                            interpret: bool = False):
     """2-D (trials × clients) grid form of the stream kernel (§11).
 
@@ -769,79 +681,18 @@ def sched_stream_grid_call(object_ids: jax.Array, lengths: jax.Array,
     ``(T / tt, C / ct)`` program instances, each vectorizing its
     ``tt * ct`` streams over VMEM sublanes.
 
-    Returns (choices (T, C, N) int32, latencies (T, C, N) f32,
-    final_tables (T, C, 4, M_pad) f32, window_loads (T, C, W, M_pad)
-    f32, metrics (T, C, MET_PAD) f32 per stream, cm_wloads (T, W,
-    M_pad) f32 — the masked client-MEAN window loads, or the raw masked
-    client SUM when ``merge_mean=False`` (the pre-reduced per-device
-    block the sharded sweep's ``psum_tree`` consumes, DESIGN.md §12) —
-    cm_metrics (T, MET_PAD) f32 cross-client merged rows, accumulated
-    in-VMEM across the client grid dimension (the MET_P99 lane holds the
-    merged nearest-rank p99 when ``merge_mean=True``, DESIGN.md §14),
-    and cm_lats / cm_lval (T, C, N) f32 — the merged latency block:
-    masked grouped-step latencies and 0/1 validity, the operand the
-    sharded sweep gathers to bisect the GLOBAL merged p99).
+    Returns the per-stream (choices (T, C, N) int32, latencies (T, C, N)
+    f32, final_tables (T, C, 4, M_pad) f32, window_loads (T, C, W,
+    M_pad) f32, metrics (T, C, MET_PAD) f32); the cross-client merges
+    are `ops.sched_stream_grid`'s.
     """
-    t, c, n = object_ids.shape
-    m_pad = tables.shape[-1]
-    n_win = win_rates.shape[1]
-    assert n == n_win * window_size, (n, n_win, window_size)
-    assert t % trial_tile == 0, (t, trial_tile)
-    assert c % client_tile == 0, (c, client_tile)
-    tt, ct = trial_tile, client_tile
-    win_dec = window_decrements(win_rates, window_dt).astype(jnp.float32)
-    kernel = functools.partial(
-        _sched_stream_kernel, n_windows=n_win, window_size=window_size,
-        n_servers=n_servers, m_pad=m_pad, t_tile=tt, threshold=threshold,
-        lam=lam, alpha=alpha, window_dt=window_dt, policy=policy,
-        observe=observe, renorm=renorm, nltr_n=nltr_n,
-        probe_choices=probe_choices, client_tile=ct,
-        n_client_blocks=c // ct, merge_mean=merge_mean)
-    return pl.pallas_call(
-        kernel,
-        grid=(t // tt, c // ct),
-        in_specs=[
-            pl.BlockSpec((tt, ct, n), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tt, ct, n), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tt, ct, n), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tt, ct, N_ROWS, m_pad), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((tt, ct), lambda i, j: (i, j)),
-            pl.BlockSpec((tt, n_win, m_pad), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((tt, n_win, m_pad), lambda i, j: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tt, ct, n), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tt, ct, n), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((tt, ct, N_ROWS, m_pad), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((tt, ct, n_win, m_pad), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((tt, ct, MET_PAD), lambda i, j: (i, j, 0)),
-            # per-TRIAL cross-client accumulators: constant in j, so the
-            # block stays VMEM-resident across a trial row's client
-            # steps and retires once per trial tile (DESIGN.md §11);
-            # the last two are the merged latency block + validity
-            # (DESIGN.md §14) — FULL client axis per block, each client
-            # step depositing its disjoint (tt, ct, n) slice
-            pl.BlockSpec((tt, n_win, m_pad), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((tt, MET_PAD), lambda i, j: (i, 0)),
-            pl.BlockSpec((tt, c, n), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((tt, c, n), lambda i, j: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((t, c, n), jnp.int32),
-            jax.ShapeDtypeStruct((t, c, n), jnp.float32),
-            jax.ShapeDtypeStruct((t, c, N_ROWS, m_pad), jnp.float32),
-            jax.ShapeDtypeStruct((t, c, n_win, m_pad), jnp.float32),
-            jax.ShapeDtypeStruct((t, c, MET_PAD), jnp.float32),
-            jax.ShapeDtypeStruct((t, n_win, m_pad), jnp.float32),
-            jax.ShapeDtypeStruct((t, MET_PAD), jnp.float32),
-            jax.ShapeDtypeStruct((t, c, n), jnp.float32),
-            jax.ShapeDtypeStruct((t, c, n), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((N_ROWS, tt * ct, m_pad), jnp.float32),
-        ],
-        interpret=interpret,
-    )(object_ids, lengths, valid, tables, seeds, win_rates, win_dec)
+    return _stream_blocks_call(
+        object_ids, lengths, valid, tables, seeds, win_rates,
+        trial_tile=trial_tile, client_tile=client_tile,
+        window_size=window_size, interpret=interpret, n_servers=n_servers,
+        threshold=threshold, lam=lam, alpha=alpha, window_dt=window_dt,
+        policy=policy, observe=observe, renorm=renorm, nltr_n=nltr_n,
+        probe_choices=probe_choices)
 
 
 def sched_select_call(object_ids: jax.Array, lengths: jax.Array,

@@ -17,8 +17,9 @@ Four entry points:
 * :func:`sched_stream_grid` — the 2-D (TRIALS × CLIENTS) grid form
   (DESIGN.md §11): the per_client contention model's whole sweep — T
   trials × C private-log clients — as ONE ``pallas_call`` with
-  ``grid = (ceil(T / trial_tile), ceil(C / client_tile))`` and the
-  cross-client merges fused in-VMEM.  ``engine.run_stream_batch`` with
+  ``grid = (ceil(T / trial_tile), ceil(C / client_tile))``; the
+  cross-client merges fold the kernel's per-stream outputs with the
+  `policy_core` twins.  ``engine.run_stream_batch`` with
   a ``(T, C)`` leading batch (and through it ``simulate.run_trials(
   backend="kernel", client_model="per_client")``) dispatches here.
 """
@@ -31,9 +32,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.policy_core import (DEFAULT_TRIAL_TILE, N_CMETRICS,
-                                    N_METRICS, init_table,
-                                    resolve_client_tile,
+from repro.core.policy_core import (N_METRICS, client_stream_metrics,
+                                    init_table, masked_client_mean,
+                                    masked_client_sum, resolve_client_tile,
                                     resolve_trial_tile)
 from repro.kernels.sched_select.kernel import (sched_select_call,
                                                sched_stream_call,
@@ -271,13 +272,15 @@ def sched_stream_grid(object_ids: jax.Array, lengths: jax.Array,
 
     Returns (choices (T, C, N) int32, latencies (T, C, N) f32,
     final_tables (T, C, 4, M) f32, window_loads (T, C, W, M) f32,
-    metrics (T, C, N_METRICS) f32 per stream, cm_wloads (T, W, M) f32 —
-    the masked client-MEAN post-drain loads, `policy_core.
-    masked_client_mean`'s in-VMEM twin, or the raw masked client SUM
+    metrics (T, C, N_METRICS) f32 per stream — the kernel's outputs —
+    and the cross-client merges of those outputs, folded here with the
+    `policy_core` twins the jax backend runs (client-tile association):
+    cm_wloads (T, W, M) f32 — the masked client-MEAN post-drain loads
+    (`policy_core.masked_client_mean`), or the raw masked client SUM
     when ``merge_mean=False`` (the per-device partial the sharded
     sweep's `policy_core.psum_tree` folds across devices, DESIGN.md
-    §12) — cm_metrics (T, N_CMETRICS) f32 cross-client merged rows,
-    `policy_core.client_stream_metrics`'s twin (its MET_P99 lane is the
+    §12) — cm_metrics (T, N_CMETRICS) f32 cross-client merged rows
+    (`policy_core.client_stream_metrics`; its MET_P99 lane is the
     MERGED nearest-rank p99 over the whole trial's latency block when
     ``merge_mean=True``, 0 otherwise — DESIGN.md §14), and cm_lats /
     cm_lval (T, C, N) f32 — the merged latency block: grouped-step
@@ -317,16 +320,33 @@ def sched_stream_grid(object_ids: jax.Array, lengths: jax.Array,
     pad = ((0, 0), (0, 0), (0, m_pad - m))
     tables_p = jnp.pad(tables, ((0, 0),) + pad)
     rates_p = jnp.pad(win_rates.astype(jnp.float32), pad)
-    choices, lats, ftab, wloads, metrics, cm_wl, cm_met, cm_lats, cm_lval = \
-        sched_stream_grid_call(
-            object_ids, lengths, valid, tables_p, seeds, rates_p,
-            n_servers=n_servers, window_size=window_size,
-            threshold=threshold, lam=lam, alpha=alpha, window_dt=window_dt,
-            policy=policy, observe=observe, renorm=renorm,
-            trial_tile=tile_t, client_tile=tile_c, nltr_n=nltr_n,
-            probe_choices=probe_choices, merge_mean=merge_mean,
-            interpret=interpret)
-    return (choices[:t, :c], lats[:t, :c], ftab[:t, :c, :, :m],
-            wloads[:t, :c, :, :m], metrics[:t, :c, :N_METRICS],
-            cm_wl[:t, :, :m], cm_met[:t, :N_CMETRICS],
-            cm_lats[:t, :c], cm_lval[:t, :c])
+    choices, lats, ftab, wloads, metrics = sched_stream_grid_call(
+        object_ids, lengths, valid, tables_p, seeds, rates_p,
+        n_servers=n_servers, window_size=window_size, threshold=threshold,
+        lam=lam, alpha=alpha, window_dt=window_dt, policy=policy,
+        observe=observe, renorm=renorm, trial_tile=tile_t,
+        client_tile=tile_c, nltr_n=nltr_n, probe_choices=probe_choices,
+        interpret=interpret)
+    choices, lats = choices[:t, :c], lats[:t, :c]
+    wloads, metrics = wloads[:t, :c, :, :m], metrics[:t, :c, :N_METRICS]
+
+    # cross-client merges over REAL clients (a client is real iff its
+    # slice holds a valid request): the policy_core twins the jax
+    # backend runs, with the kernel grid's client-tile association
+    validb = valid[:t, :c] != 0
+    cvalid = jnp.any(validb, axis=-1)                        # (T, C)
+    cm_lats = jnp.where(validb, lats, 0.0)
+    cm_lval = jnp.where(validb, 1.0, 0.0)
+    merge_wl = masked_client_mean if merge_mean else masked_client_sum
+    cm_wl = jax.vmap(lambda w, v: merge_wl(w, v, tile_c))(wloads, cvalid)
+    if merge_mean:
+        cm_met = jax.vmap(
+            lambda mt, v, ml, mv: client_stream_metrics(
+                mt, v, tile_c, merged_lats=ml, merged_valid=mv)
+        )(metrics, cvalid, cm_lats, validb)
+    else:
+        cm_met = jax.vmap(
+            lambda mt, v: client_stream_metrics(mt, v, tile_c)
+        )(metrics, cvalid)
+    return (choices, lats, ftab[:t, :c, :, :m], wloads, metrics, cm_wl,
+            cm_met, cm_lats, cm_lval)

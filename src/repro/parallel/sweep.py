@@ -17,7 +17,7 @@ Bit-exactness is the whole design:
   tile;
 * the CLIENT axis adds one more association level to the cross-client
   merge: each device folds its local clients with
-  `policy_core.masked_client_sum` (in-VMEM on the kernel backend with
+  `policy_core.masked_client_sum` (on the kernel backend with
   ``merge_mean=False`` — raw SUM blocks, a mean is not cross-device
   composable), then `policy_core.psum_tree` — ``all_gather`` + the
   pinned `tree_sum` halving tree, never a backend ``psum`` — folds the
@@ -56,8 +56,8 @@ class SweepMerge(NamedTuple):
     fold consumes.
 
     ``p99`` is the GLOBAL cross-client nearest-rank p99 (DESIGN.md
-    §14): each device's local merged latency block (the kernel's
-    in-VMEM ``ClientMerge.lats``/``lats_valid`` pair, or the jax twin
+    §14): each device's local merged latency block (the kernel path's
+    ``ClientMerge.lats``/``lats_valid`` pair, or the jax twin
     `engine.grouped_latency_block`) is ``all_gather``ed over the client
     mesh axis and bisected ONCE with `policy_core.nearest_rank_p99` —
     which is order- and layout-insensitive, so the gathered shard order
@@ -176,7 +176,7 @@ def run_sweep(states, works, keys, *, mesh_shape: Optional[Tuple[int, ...]],
         c_loc = cvalid.shape[1]
         ct = policy_core.resolve_client_tile(c_loc, client_tile)
         if merged is not None:
-            # kernel backend: the in-VMEM merge shipped raw SUM blocks
+            # kernel backend: the grid's merge shipped raw SUM blocks
             # (merge_mean=False above) plus the raw merged latency block
             wl_sum = merged.window_loads_mean
             n_real = merged.metrics[:, policy_core.MET_N_CLIENTS]
@@ -184,7 +184,7 @@ def run_sweep(states, works, keys, *, mesh_shape: Optional[Tuple[int, ...]],
             lats_loc = merged.lats
             lval_loc = merged.lats_valid != 0.0
         else:
-            # jax backend: the host twins of the in-VMEM merge
+            # jax backend: the same policy_core merges
             wl_sum = jax.vmap(
                 lambda w, v: policy_core.masked_client_sum(w, v, ct)
             )(res.window_loads, cvalid)

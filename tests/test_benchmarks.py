@@ -1,4 +1,5 @@
-"""Benchmark tooling regressions: BENCH_sched.json trajectory rendering.
+"""Benchmark tooling regressions: BENCH_sched.json trajectory rendering,
+the sharded series' process layout and the entry points' compile cache.
 
 The trajectory renderer consumes a history file that GROWS its schema
 over time (new series appear per PR) and can be empty or half-written
@@ -181,3 +182,55 @@ def test_check_regression_trivial_passes(tmp_path, capsys):
                              {"ts": 2.0}]))
     assert run_mod.check_regression(str(p)) == 0            # no clean base
     capsys.readouterr()
+
+
+def test_sharded_sweep_runs_in_process_off_forced_cpu(monkeypatch, capsys):
+    """Off the forced-host-device CPU setup (as on a chip) the sharded
+    series runs in THIS process over sub-meshes, measures only the
+    device counts dividing jax.device_count(), starts no child process,
+    and names the device it ran on."""
+    import jax
+
+    def no_child(*a, **k):
+        raise AssertionError("the sharded series started a child process")
+
+    monkeypatch.setattr(sched_perf, "_forced_host_devices", lambda: False)
+    monkeypatch.setattr(sched_perf.subprocess, "run", no_child)
+    n = jax.device_count()
+    out = sched_perf.sharded_sweep_throughput.__wrapped__(
+        n_servers=8, n_requests=32, window_size=8, n_trials=4, reps=1,
+        devices=(1, 2, 3))
+    assert out["devices"] == [d for d in (1, 2, 3) if n % d == 0]
+    assert out["platform"] == jax.devices()[0].platform
+    assert out["device_kind"] == jax.devices()[0].device_kind
+    assert out["sharded_bit_exact"] and out["sharded_cross_backend_exact"]
+    assert "sharded sweep throughput" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_is_env_or_fixed_checkout_path(monkeypatch,
+                                                          tmp_path, env_dir):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR when set
+    (and then no other directory is configured), else <repo>/.jax_cache."""
+    import os
+
+    import jax
+
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        want = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv(compile_cache.ENV_VAR, want)
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        if env_dir is None:
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
