@@ -573,6 +573,12 @@ def run_stream_batch(states: SchedState, works: Workload, keys: jax.Array, *,
     phases for differential per-phase profiling (DESIGN.md §16, see
     `repro.tune.profile.kernel_phase_profile`); outputs past the
     dropped phase are zeros, so nonzero levels are timing-only.
+
+    Stages (`repro.tune.profile.stage`, name scopes read from the
+    device trace, DESIGN.md §16): ``engine_prep`` covers the split,
+    grouping, seeds and window rates, ``kernel`` the kernel wrapper
+    (whose (T, C) form scopes its cross-client merge ``merge``), and
+    ``book`` the bookkeeping.  They name the ops; they time nothing.
     """
     from repro.kernels.sched_select import ops as kops
 
@@ -631,23 +637,28 @@ def run_stream_batch(states: SchedState, works: Workload, keys: jax.Array, *,
     with tune_profile.stage("engine_prep"):
         g_obj, g_lens, g_val, seeds, val, req_to_step = \
             vprep(states, works, keys)
-    if traces is not None:
-        win_rates = jax.vmap(
-            lambda tr: _window_rates(None, tr, n_win, window_dt)
-        )(traces)
-    else:
-        # 2-D: rates are per TRIAL (client-shared) — read client 0's row
-        rate_states = (jax.tree.map(lambda a: a[:, 0], states) if two_d
-                       else states)
-        win_rates = jax.vmap(
-            lambda st: _window_rates(st, None, n_win, window_dt)
-        )(rate_states)
+        if traces is not None:
+            win_rates = jax.vmap(
+                lambda tr: _window_rates(None, tr, n_win, window_dt)
+            )(traces)
+        else:
+            # 2-D: rates are per TRIAL (client-shared) — read client 0's
+            # row
+            rate_states = (jax.tree.map(lambda a: a[:, 0], states)
+                           if two_d else states)
+            win_rates = jax.vmap(
+                lambda st: _window_rates(st, None, n_win, window_dt)
+            )(rate_states)
 
     kw = dict(n_servers=m, window_size=window_size,
               threshold=policy.threshold, lam=log_cfg.lam,
               alpha=log_cfg.ewma_alpha, window_dt=window_dt,
               policy=policy.name, observe=observe, renorm=log_cfg.renorm,
               nltr_n=policy.nltr_n, probe_choices=policy.probe_choices)
+    # ``kernel`` scopes the jitted wrapper's call, not the pallas_call
+    # inside it: the custom call takes its HLO name from the innermost
+    # scope, and keeps the wrapper's (``sched_stream_batch.1``).  The
+    # (T, C) wrapper scopes its cross-client merge ``merge`` inside.
     with tune_profile.stage("kernel"):
         if two_d:
             (choices, lats, tables, wloads, metrics,

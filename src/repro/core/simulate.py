@@ -611,11 +611,15 @@ def _sched_trials(cfg: SimConfig, policy: PolicyConfig, log_cfg: LogConfig,
                 f"scheduling with window_size_eff={win} — sweeps "
                 "comparing window sizes across client counts are "
                 "comparing different windows", stacklevel=2)
-        run_works = _split_clients(works, c, per, pad)
-        run_keys = jax.vmap(lambda k: jax.random.split(k, c))(k_sched)
-        run_states = jax.tree.map(
-            lambda a: jnp.broadcast_to(a[:, None], (t, c) + a.shape[1:]),
-            states)
+        # each trial's stream split into its clients' slices: the first
+        # of the engine's input splits, scoped with them
+        with tune_profile.stage("engine_prep"):
+            run_works = _split_clients(works, c, per, pad)
+            run_keys = jax.vmap(lambda k: jax.random.split(k, c))(k_sched)
+            run_states = jax.tree.map(
+                lambda a: jnp.broadcast_to(a[:, None],
+                                           (t, c) + a.shape[1:]),
+                states)
     else:
         win = cfg.window_size
         run_works, run_keys, run_states = works, k_sched, states
@@ -667,35 +671,37 @@ def _sched_trials(cfg: SimConfig, policy: PolicyConfig, log_cfg: LogConfig,
         # cross-client fold: true loads are the cross-client sums (the
         # request order is the original stream), the contention
         # aggregates the masked merges over REAL clients
-        r = cfg.n_requests
-        ct = eff_ct          # the resolved association width (see above)
-        cvalid = jnp.any(run_works.valid, axis=-1)           # (T, C)
-        chosen = res.chosen.reshape(t, c * per)[:, :r]
-        redirected = res.redirected.reshape(t, c * per)[:, :r]
-        latencies = res.latencies.reshape(t, c * per)[:, :r]
-        probes = jnp.sum(jnp.where(cvalid, res.probe_msgs, 0),
-                         axis=-1).astype(jnp.int32)
-        if smerge is not None:
-            # the sharded sweep's collective merge (parallel/sweep.py):
-            # already the global mean/max/sum rows, uniform across
-            # backends
-            wl = smerge.window_loads_mean
-            phase = smerge.phase_time
-            probes = smerge.probe_msgs
-        elif merged is not None:
-            # the 2-D grid kernel path's merge (bit-identical to the
-            # jax branch below — asserted in tests/test_simulate.py)
-            wl = merged.window_loads_mean
-            phase = merged.metrics[:, policy_core.MET_MAKESPAN]
-        else:
-            wl = jax.vmap(
-                lambda w, v: policy_core.masked_client_mean(w, v, ct)
-            )(res.window_loads, cvalid)
-            w_open = ((jnp.arange(per) // win).astype(jnp.float32)
-                      * jnp.float32(window_dt))
-            comp = jnp.where(run_works.valid,
-                             w_open[None, None, :] + res.latencies, 0.0)
-            phase = jnp.max(comp, axis=(1, 2))
+        with tune_profile.stage("merge"):
+            r = cfg.n_requests
+            ct = eff_ct      # the resolved association width (see above)
+            cvalid = jnp.any(run_works.valid, axis=-1)       # (T, C)
+            chosen = res.chosen.reshape(t, c * per)[:, :r]
+            redirected = res.redirected.reshape(t, c * per)[:, :r]
+            latencies = res.latencies.reshape(t, c * per)[:, :r]
+            probes = jnp.sum(jnp.where(cvalid, res.probe_msgs, 0),
+                             axis=-1).astype(jnp.int32)
+            if smerge is not None:
+                # the sharded sweep's collective merge
+                # (parallel/sweep.py): already the global mean/max/sum
+                # rows, uniform across backends
+                wl = smerge.window_loads_mean
+                phase = smerge.phase_time
+                probes = smerge.probe_msgs
+            elif merged is not None:
+                # the 2-D grid kernel path's merge (bit-identical to the
+                # jax branch below — asserted in tests/test_simulate.py)
+                wl = merged.window_loads_mean
+                phase = merged.metrics[:, policy_core.MET_MAKESPAN]
+            else:
+                wl = jax.vmap(
+                    lambda w, v: policy_core.masked_client_mean(w, v, ct)
+                )(res.window_loads, cvalid)
+                w_open = ((jnp.arange(per) // win).astype(jnp.float32)
+                          * jnp.float32(window_dt))
+                comp = jnp.where(run_works.valid,
+                                 w_open[None, None, :] + res.latencies,
+                                 0.0)
+                phase = jnp.max(comp, axis=(1, 2))
     else:
         chosen, redirected = res.chosen, res.redirected
         latencies, probes, wl = res.latencies, res.probe_msgs, \
@@ -749,10 +755,11 @@ def _run_batched(keys: jax.Array, cfg: SimConfig, policy: PolicyConfig,
     which is how `benchmarks/sched_perf.py` times the prep/sched/post
     phase breakdown.
 
-    The `repro.tune.profile.stage` wrappers are inert unless a
-    ``profile.collect()`` block is active (an eager profiling run);
-    under normal jitted dispatch they cost nothing and record nothing
-    (timing a traced stage would measure tracing, DESIGN.md §16)."""
+    The `repro.tune.profile.stage` wrappers are name scopes: every
+    compiled op carries its stage path (``prep``, ``sched/...``,
+    ``post``) in its ``op_name`` metadata, and a device trace splits the
+    sweep's device time by them (DESIGN.md §16).  They change nothing
+    in the compiled program and cost nothing at run time."""
     with tune_profile.stage("prep"):
         prep = _prep_trials(keys, cfg, log_cfg)
     init, strag_mask, works, states, traces, k_sched = prep
@@ -782,7 +789,8 @@ def run_trials(key: jax.Array, cfg: SimConfig, policy: PolicyConfig,
     vmapped jax engine (asserted in tests/test_kernels.py and
     tests/test_simulate.py)."""
     policies.validate_policy(policy, cfg.n_servers)
-    keys = jax.random.split(key, cfg.n_trials)
+    with tune_profile.stage("prep"):
+        keys = jax.random.split(key, cfg.n_trials)
     return _run_batched(keys, cfg, policy, log_cfg)
 
 

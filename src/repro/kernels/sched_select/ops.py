@@ -39,6 +39,7 @@ from repro.core.policy_core import (N_METRICS, client_stream_metrics,
 from repro.kernels.sched_select.kernel import (sched_select_call,
                                                sched_stream_call,
                                                sched_stream_grid_call)
+from repro.tune import profile as tune_profile
 
 POLICIES = ("minload", "two_random", "ect", "trh", "rr", "two_choice",
             "mlml", "nltr")
@@ -333,20 +334,21 @@ def sched_stream_grid(object_ids: jax.Array, lengths: jax.Array,
     # cross-client merges over REAL clients (a client is real iff its
     # slice holds a valid request): the policy_core twins the jax
     # backend runs, with the kernel grid's client-tile association
-    validb = valid[:t, :c] != 0
-    cvalid = jnp.any(validb, axis=-1)                        # (T, C)
-    cm_lats = jnp.where(validb, lats, 0.0)
-    cm_lval = jnp.where(validb, 1.0, 0.0)
-    merge_wl = masked_client_mean if merge_mean else masked_client_sum
-    cm_wl = jax.vmap(lambda w, v: merge_wl(w, v, tile_c))(wloads, cvalid)
-    if merge_mean:
-        cm_met = jax.vmap(
-            lambda mt, v, ml, mv: client_stream_metrics(
-                mt, v, tile_c, merged_lats=ml, merged_valid=mv)
-        )(metrics, cvalid, cm_lats, validb)
-    else:
-        cm_met = jax.vmap(
-            lambda mt, v: client_stream_metrics(mt, v, tile_c)
-        )(metrics, cvalid)
+    with tune_profile.stage("merge"):
+        validb = valid[:t, :c] != 0
+        cvalid = jnp.any(validb, axis=-1)                        # (T, C)
+        cm_lats = jnp.where(validb, lats, 0.0)
+        cm_lval = jnp.where(validb, 1.0, 0.0)
+        merge_wl = masked_client_mean if merge_mean else masked_client_sum
+        cm_wl = jax.vmap(lambda w, v: merge_wl(w, v, tile_c))(wloads, cvalid)
+        if merge_mean:
+            cm_met = jax.vmap(
+                lambda mt, v, ml, mv: client_stream_metrics(
+                    mt, v, tile_c, merged_lats=ml, merged_valid=mv)
+            )(metrics, cvalid, cm_lats, validb)
+        else:
+            cm_met = jax.vmap(
+                lambda mt, v: client_stream_metrics(mt, v, tile_c)
+            )(metrics, cvalid)
     return (choices, lats, ftab[:t, :c, :, :m], wloads, metrics, cm_wl,
             cm_met, cm_lats, cm_lval)

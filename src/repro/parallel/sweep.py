@@ -44,6 +44,7 @@ from jax.sharding import PartitionSpec as P
 from repro.compat import shard_map_unchecked
 from repro.core import engine, policy_core
 from repro.launch.mesh import make_sweep_mesh
+from repro.tune import profile as tune_profile
 
 
 class SweepMerge(NamedTuple):
@@ -168,57 +169,58 @@ def run_sweep(states, works, keys, *, mesh_shape: Optional[Tuple[int, ...]],
         if not two_d:
             return res, metrics, None
 
-        # ---- cross-client merge: per-device partials with the local
-        # masked_client_sum association, folded across the client mesh
-        # axis by psum_tree (sums), pmax (makespan) and psum (integer
-        # probe counts)
-        cvalid = jnp.any(works.valid, axis=-1)        # (t_loc, c_loc)
-        c_loc = cvalid.shape[1]
-        ct = policy_core.resolve_client_tile(c_loc, client_tile)
-        if merged is not None:
-            # kernel backend: the grid's merge shipped raw SUM blocks
-            # (merge_mean=False above) plus the raw merged latency block
-            wl_sum = merged.window_loads_mean
-            n_real = merged.metrics[:, policy_core.MET_N_CLIENTS]
-            phase_loc = merged.metrics[:, policy_core.MET_MAKESPAN]
-            lats_loc = merged.lats
-            lval_loc = merged.lats_valid != 0.0
-        else:
-            # jax backend: the same policy_core merges
-            wl_sum = jax.vmap(
-                lambda w, v: policy_core.masked_client_sum(w, v, ct)
-            )(res.window_loads, cvalid)
-            n_real = jax.vmap(
-                lambda v: policy_core.masked_client_sum(
-                    jnp.ones(v.shape, jnp.float32), v, ct))(cvalid)
-            per = works.valid.shape[-1]
-            w_open = ((jnp.arange(per) // window_size).astype(jnp.float32)
-                      * jnp.float32(window_dt))
-            comp = jnp.where(works.valid,
-                             w_open[None, None, :] + res.latencies, 0.0)
-            phase_loc = jnp.max(comp, axis=(1, 2))
-            lats_loc, lval_loc = engine.grouped_latency_block(
-                works, res.latencies, window_size, group_steps)
-        probes_loc = jnp.sum(jnp.where(cvalid, res.probe_msgs, 0),
-                             axis=-1).astype(jnp.int32)
-        if collective:
-            wl_sum = policy_core.psum_tree(wl_sum, "clients")
-            n_real = policy_core.psum_tree(n_real, "clients")
-            phase_loc = jax.lax.pmax(phase_loc, "clients")
-            probes_loc = jax.lax.psum(probes_loc, "clients")
-            # global p99: gather every device's raw block and bisect
-            # ONCE — `nearest_rank_p99` is order-insensitive, so the
-            # shard-major gather layout is immaterial (DESIGN.md §14)
-            lats_loc = jax.lax.all_gather(lats_loc, "clients", axis=1)
-            lval_loc = jax.lax.all_gather(lval_loc, "clients", axis=1)
-        t_loc = lats_loc.shape[0]
-        p99 = policy_core.nearest_rank_p99(
-            lats_loc.reshape(t_loc, -1), lval_loc.reshape(t_loc, -1))[:, 0]
-        wl_mean = wl_sum / jnp.maximum(n_real, 1.0)[:, None, None]
-        return res, metrics, SweepMerge(window_loads_mean=wl_mean,
-                                        phase_time=phase_loc,
-                                        probe_msgs=probes_loc,
-                                        p99=p99)
+        # ---- cross-client merge (stage ``merge``): per-device partials
+        # with the local masked_client_sum association, folded across the
+        # client mesh axis by psum_tree (sums), pmax (makespan) and psum
+        # (integer probe counts)
+        with tune_profile.stage("merge"):
+            cvalid = jnp.any(works.valid, axis=-1)        # (t_loc, c_loc)
+            c_loc = cvalid.shape[1]
+            ct = policy_core.resolve_client_tile(c_loc, client_tile)
+            if merged is not None:
+                # kernel backend: the grid's merge shipped raw SUM blocks
+                # (merge_mean=False above) plus the raw merged latency block
+                wl_sum = merged.window_loads_mean
+                n_real = merged.metrics[:, policy_core.MET_N_CLIENTS]
+                phase_loc = merged.metrics[:, policy_core.MET_MAKESPAN]
+                lats_loc = merged.lats
+                lval_loc = merged.lats_valid != 0.0
+            else:
+                # jax backend: the same policy_core merges
+                wl_sum = jax.vmap(
+                    lambda w, v: policy_core.masked_client_sum(w, v, ct)
+                )(res.window_loads, cvalid)
+                n_real = jax.vmap(
+                    lambda v: policy_core.masked_client_sum(
+                        jnp.ones(v.shape, jnp.float32), v, ct))(cvalid)
+                per = works.valid.shape[-1]
+                w_open = ((jnp.arange(per) // window_size).astype(jnp.float32)
+                          * jnp.float32(window_dt))
+                comp = jnp.where(works.valid,
+                                 w_open[None, None, :] + res.latencies, 0.0)
+                phase_loc = jnp.max(comp, axis=(1, 2))
+                lats_loc, lval_loc = engine.grouped_latency_block(
+                    works, res.latencies, window_size, group_steps)
+            probes_loc = jnp.sum(jnp.where(cvalid, res.probe_msgs, 0),
+                                 axis=-1).astype(jnp.int32)
+            if collective:
+                wl_sum = policy_core.psum_tree(wl_sum, "clients")
+                n_real = policy_core.psum_tree(n_real, "clients")
+                phase_loc = jax.lax.pmax(phase_loc, "clients")
+                probes_loc = jax.lax.psum(probes_loc, "clients")
+                # global p99: gather every device's raw block and bisect
+                # ONCE — `nearest_rank_p99` is order-insensitive, so the
+                # shard-major gather layout is immaterial (DESIGN.md §14)
+                lats_loc = jax.lax.all_gather(lats_loc, "clients", axis=1)
+                lval_loc = jax.lax.all_gather(lval_loc, "clients", axis=1)
+            t_loc = lats_loc.shape[0]
+            p99 = policy_core.nearest_rank_p99(
+                lats_loc.reshape(t_loc, -1), lval_loc.reshape(t_loc, -1))[:, 0]
+            wl_mean = wl_sum / jnp.maximum(n_real, 1.0)[:, None, None]
+            return res, metrics, SweepMerge(window_loads_mean=wl_mean,
+                                            phase_time=phase_loc,
+                                            probe_msgs=probes_loc,
+                                            p99=p99)
 
     f = shard_map_unchecked(
         body, mesh,

@@ -6,10 +6,10 @@ Three pieces:
   (``TUNE_sched.json``) and :func:`~repro.tune.table.resolve_sim_tiles`,
   the ONE resolution point `simulate._sched_trials` routes every
   backend's (trial_tile, client_tile) through;
-* :mod:`repro.tune.profile` — wall-clock stage hooks (used by
-  `simulate._run_batched` / `engine.run_stream_batch`) plus the
-  differential kernel phase profiler built on the kernel's ``ablate``
-  levels;
+* :mod:`repro.tune.profile` — the stage scopes (``jax.named_scope``,
+  read back from the device trace) around the sweep's pipeline stages,
+  plus the differential kernel phase profiler built on the kernel's
+  ``ablate`` levels;
 * :mod:`repro.tune.autotune` — the candidate sweep that times tile
   shapes and caches the winner (imported lazily: it depends on
   `repro.core.simulate`, which itself imports :mod:`repro.tune.table`).

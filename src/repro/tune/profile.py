@@ -1,15 +1,29 @@
-"""Wall-clock profiling hooks + the differential kernel phase profiler.
+"""Stage scopes + the differential kernel phase profiler.
 
 Two layers (DESIGN.md §16):
 
-* STAGE HOOKS — `simulate._run_batched` and `engine.run_stream_batch`
-  wrap their pipeline stages in :func:`stage`.  The hooks are inert (a
-  no-op context) unless a :func:`collect` block is active, so they cost
-  nothing on the hot path and nothing under tracing; a profiler that
-  wants real wall numbers runs the pipeline eagerly (or stage-jitted,
-  see :func:`pipeline_stage_profile`) inside ``collect()``.  Timing
-  lives HERE, not in the engine files — the scheduling surface is under
-  the `contractcheck` CC-TIME rule (no clocks near the contract code).
+* STAGE SCOPES — `simulate.run_trials`, `simulate._run_batched`,
+  `engine.run_stream_batch`, the `kernels.sched_select.ops` grid
+  wrappers and `parallel.sweep.run_sweep` wrap their pipeline stages in
+  :func:`stage`, a ``jax.named_scope``.  A name scope is metadata at
+  trace time: every compiled op carries its stage path in its
+  ``op_name`` (``jit(run_trials)/sched/book/...``), the compiled
+  program is otherwise the same, and nothing runs at run time, so there
+  is no switch.  The stages are read back from the device trace: the
+  benchmark's ``bench/stagetrace.py`` sums each device op's time under
+  the innermost leaf stage of its path (:data:`STAGES`).  The stages,
+  outermost first::
+
+      prep              trial keys, workloads, loads, traces
+      sched             parent of the four below
+        engine_prep     client and window split, step grouping, seeds,
+                        per-window rates
+        kernel          the kernel wrapper: pads, casts, the
+                        pallas_call, its slices
+        merge           the per_client cross-client merge (the grid
+                        wrapper's, inside ``kernel``, and the fold after)
+        book            the kernel-path bookkeeping
+      post              the TrialResult stack
 
 * KERNEL PHASE PROFILER — :func:`kernel_phase_profile` attributes the
   trial-grid kernel's wall time to its window phases by DIFFERENTIAL
@@ -19,49 +33,37 @@ Two layers (DESIGN.md §16):
   and ``dispatch_s = t3`` (grid dispatch + per-window renorm/drain
   bookkeeping — the interpret-mode floor).  A clock inside the fused
   kernel body is impossible (and banned by CC-TIME), so ablation is the
-  only honest per-phase attribution.
+  only honest per-phase attribution.  Timing lives HERE, not in the
+  engine files — the scheduling surface is under the `contractcheck`
+  CC-TIME rule (no clocks near the contract code).
 """
 
 from __future__ import annotations
 
-import contextlib
 import time
-from typing import Callable, Dict, Iterator, Optional
+from typing import Callable, Dict, Optional
 
-_ACTIVE: Optional[Dict[str, float]] = None
+import jax
 
-
-@contextlib.contextmanager
-def collect() -> Iterator[Dict[str, float]]:
-    """Activate the stage hooks; yields the {stage: seconds} dict they
-    accumulate into (re-entrant: nested collects see their own dict)."""
-    global _ACTIVE
-    prev, _ACTIVE = _ACTIVE, {}
-    try:
-        yield _ACTIVE
-    finally:
-        _ACTIVE = prev
+# The leaf stages, outermost first: every device op of a sweep that is
+# not the kernel or a collective falls under exactly one of them (or
+# under the bare ``sched`` parent).  The benchmark keeps a copy of this
+# tuple (it imports nothing of the program); a test holds the two equal.
+STAGES = ("prep", "engine_prep", "kernel", "merge", "book", "post")
+PARENTS = ("sched",)
 
 
-@contextlib.contextmanager
-def stage(name: str) -> Iterator[None]:
-    """Accumulate the block's wall time under ``name`` when a collect()
-    is active; otherwise a zero-cost no-op."""
-    if _ACTIVE is None:
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        _ACTIVE[name] = _ACTIVE.get(name, 0.0) + time.perf_counter() - t0
+def stage(name: str):
+    """``jax.named_scope(name)`` for a stage of :data:`STAGES` or a
+    parent of :data:`PARENTS`."""
+    if name not in STAGES + PARENTS:
+        raise ValueError(f"stage {name!r} is not one of {STAGES + PARENTS}")
+    return jax.named_scope(name)
 
 
 def median_time(run: Callable[[], object], reps: int = 3) -> float:
     """Median wall seconds of ``run()`` over ``reps`` timed calls after
     one untimed warmup (compile + cache)."""
-    import jax
-
     jax.block_until_ready(run())
     times = []
     for _ in range(max(reps, 1)):
@@ -79,8 +81,6 @@ def best_time(run: Callable[[], object], reps: int = 3) -> float:
     preemption, cache pollution from a neighbouring stage); on a busy
     single-core container one such spike under a median flips candidate
     winners between tuning runs, while the minimum stays stable."""
-    import jax
-
     jax.block_until_ready(run())
     best = float("inf")
     for _ in range(max(reps, 1)):
@@ -102,8 +102,6 @@ def kernel_phase_profile(*, n_servers: int = 100, n_requests: int = 2000,
     "dispatch_s"}`` — the last four are clamped nonnegative and the
     deltas are taken on one shared prep, so engine-side dispatch costs
     cancel out of every phase except the ``dispatch_s`` floor."""
-    import jax
-
     from repro.core import simulate
     from repro.core.policies import PolicyConfig
     from repro.core.statlog import LogConfig
@@ -137,37 +135,3 @@ def kernel_phase_profile(*, n_servers: int = 100, n_requests: int = 2000,
         "plan_s": max(t[2] - t[3], 0.0),
         "dispatch_s": t[3],
     }
-
-
-def pipeline_stage_profile(cfg, policy, log_cfg, *, reps: int = 3,
-                           seed: int = 0) -> Dict[str, float]:
-    """Per-stage wall times of the `simulate._run_batched` pipeline —
-    each stage jitted independently (cfg/policy/log_cfg static, the
-    DESIGN.md §14 property) and timed end to end."""
-    import jax
-
-    from repro.core import simulate
-
-    keys = jax.random.split(jax.random.key(seed), cfg.n_trials)
-    prep_jit = jax.jit(simulate._prep_trials, static_argnums=(1, 2))
-    sched_jit = jax.jit(simulate._sched_trials, static_argnums=(0, 1, 2))
-    post_jit = jax.jit(simulate._post_trials, static_argnums=(0,))
-
-    out: Dict[str, float] = {}
-    with collect() as stages:
-        with stage("prep"):
-            prep = jax.block_until_ready(prep_jit(keys, cfg, log_cfg))
-        init, strag_mask, works, states, traces, k_sched = prep
-        with stage("sched"):
-            sched = jax.block_until_ready(sched_jit(
-                cfg, policy, log_cfg, works, states, k_sched, traces))
-        with stage("post"):
-            jax.block_until_ready(post_jit(
-                cfg, init, strag_mask, works, traces, *sched))
-    # first pass included compilation; re-time the dominant sched stage
-    out["prep_s"] = stages["prep"]
-    out["post_s"] = stages["post"]
-    out["sched_s"] = median_time(
-        lambda: sched_jit(cfg, policy, log_cfg, works, states, k_sched,
-                          traces), reps=reps)
-    return out
