@@ -80,9 +80,13 @@ class ClusterTrace(NamedTuple):
 
 
 def rates_at(trace: ClusterTrace, t: jax.Array) -> jax.Array:
-    """(M,) service rates in effect at virtual time ``t``."""
-    idx = jnp.sum(trace.times <= t) - 1
-    return trace.rates[jnp.clip(idx, 0, trace.n_events - 1)]
+    """(M,) service rates in effect at virtual time ``t``.
+
+    The event's row is picked by a masked sum over the few events with a
+    single non-zero term, an exact relocation with no gather op."""
+    idx = jnp.clip(jnp.sum(trace.times <= t) - 1, 0, trace.n_events - 1)
+    pick = jnp.arange(trace.n_events) == idx
+    return jnp.sum(jnp.where(pick[:, None], trace.rates, 0.0), axis=0)
 
 
 class ScheduleResult(NamedTuple):
@@ -99,36 +103,58 @@ class ScheduleResult(NamedTuple):
     #                          keeps its LCG in VMEM)
 
 
+def _run_totals(x, keys):
+    """Sum of each run of equal ``keys`` along the last axis, on the
+    run's first row (rows of a run are adjacent).  A segmented suffix
+    scan: level ``d`` adds the partial sum ``2**d`` rows on where that
+    row is in the same run.  The association is fixed by the run's
+    length alone, in row order (its first power-of-two block, plus the
+    rest), so every batch shape and context gives the same bits; a run
+    of one or two rows is exact (DESIGN.md §18)."""
+    w = x.shape[-1]
+    shift = 1
+    while shift < w:
+        same = jnp.concatenate(
+            [keys[..., shift:] == keys[..., :-shift],
+             jnp.zeros(keys.shape[:-1] + (shift,), bool)], axis=-1)
+        nxt = jnp.concatenate(
+            [x[..., shift:], jnp.zeros(x.shape[:-1] + (shift,), x.dtype)],
+            axis=-1)
+        x = jnp.where(same, x + nxt, x)
+        shift *= 2
+    return x
+
+
 def group_by_object_with_map(work: Workload) -> Tuple[Workload, jax.Array]:
     """Form steps: aggregate same-object requests into one decision (§3.2).
 
-    Static-shape friendly: output has the same length R; the first
-    occurrence of each object carries the summed length, duplicates are
-    marked invalid (zero length).  Also returns ``req_to_step``: for every
-    ORIGINAL request index, the row of its aggregated step (so per-request
-    results can be scattered back).
+    Static-shape friendly: output has the same length R, rows in the
+    order of a stable sort by object id with invalid rows last; the
+    first occurrence of each object carries the summed length,
+    duplicates are marked invalid (zero length).  Also returns
+    ``req_to_step``: for every ORIGINAL request index, the row of its
+    aggregated step (so per-request results can be relocated back with
+    `policy_core.permute_from_sorted`).
+
+    Window-local dense form (DESIGN.md §18): one all-pairs integer rank
+    (`policy_core.rank_asc`) orders the rows and single-non-zero masked
+    sums move them; no gather, scatter or sort.  Fields are ``(..., R)``
+    with any leading batch axes, each row of R grouped on its own.
     """
-    r = work.n_requests
-    ids = jnp.where(work.valid, work.object_ids, jnp.iinfo(jnp.int32).max)
-    # contract-ok: CC-SORT engine-side step grouping keeps backend argsort (§10)
-    order = jnp.argsort(ids, stable=True)
-    s_ids = ids[order]
-    s_len = work.lengths[order] * work.valid[order]
-    is_first = jnp.concatenate([jnp.ones((1,), bool), s_ids[1:] != s_ids[:-1]])
-    # segment id per sorted row = running count of firsts - 1
-    # contract-ok: CC-CUMSUM integer prefix count — association-free (§9)
-    seg = jnp.cumsum(is_first) - 1
-    summed = jax.ops.segment_sum(s_len, seg, num_segments=r)
-    agg_len = jnp.where(is_first, summed[seg], 0.0)
-    agg_valid = is_first & (s_ids != jnp.iinfo(jnp.int32).max)
+    big = jnp.iinfo(jnp.int32).max
+    ids = jnp.where(work.valid, work.object_ids, big)
+    rank, req_to_step = policy_core.rank_asc(ids)
+    s_ids, s_len = policy_core.permute_to_sorted(
+        rank, (ids, work.lengths * work.valid))
+    is_first = jnp.concatenate(
+        [jnp.ones(s_ids.shape[:-1] + (1,), bool),
+         s_ids[..., 1:] != s_ids[..., :-1]], axis=-1)
+    agg_valid = is_first & (s_ids != big)
     grouped = Workload(
         object_ids=jnp.where(agg_valid, s_ids, 0).astype(jnp.int32),
-        lengths=agg_len.astype(jnp.float32),
+        lengths=jnp.where(is_first, _run_totals(s_len, s_ids),
+                          0.0).astype(jnp.float32),
         valid=agg_valid)
-    rows = jnp.arange(r, dtype=jnp.int32)
-    seg_first = jax.ops.segment_min(rows, seg, num_segments=r)  # step row
-    inv_order = jnp.zeros((r,), jnp.int32).at[order].set(rows)
-    req_to_step = seg_first[seg[inv_order]]
     return grouped, req_to_step
 
 
@@ -205,11 +231,11 @@ def run_window(state: SchedState, work: Workload, key: jax.Array, *,
     if log_cfg.renorm:
         state = statlog.renormalize(state)
 
-    # scatter back: plan order -> step order -> original request order.
-    # The engine keeps XLA's gather/scatter here; the kernel's §13
+    # back to request order: plan order -> step order -> original order.
+    # The plan's unpermute keeps XLA's gather/scatter; the kernel's §13
     # inverse permutation apply (permute_from_sorted) computes the SAME
-    # relocation (property-pinned in tests/test_policies.py), so the
-    # backends stay bit-exact without sharing this code path.
+    # relocation (property-pinned in tests/test_policies.py).  The step
+    # -> request relocation is the kernel path's own (§18).
     if reorders:
         inv = jnp.zeros((r,), jnp.int32).at[plan.order].set(pos)
         chosen_sorted = chosen_sorted[inv]
@@ -219,9 +245,10 @@ def run_window(state: SchedState, work: Workload, key: jax.Array, *,
     redirected = redir_sorted & work.valid
     latencies = lat_sorted * work.valid
     if req_to_step is not None:
-        chosen = chosen[req_to_step]
-        redirected = redirected[req_to_step] & orig_work.valid
-        latencies = latencies[req_to_step] * orig_work.valid
+        chosen, redirected, latencies = policy_core.permute_from_sorted(
+            req_to_step, (chosen, redirected.astype(jnp.int32), latencies))
+        redirected = (redirected != 0) & orig_work.valid
+        latencies = latencies * orig_work.valid
     probes = (jnp.sum(work.valid) * policy.probes_per_request).astype(jnp.int32)
     return ScheduleResult(state=state, chosen=chosen, probe_msgs=probes,
                           redirected=redirected, latencies=latencies,
@@ -262,7 +289,7 @@ def grouped_latency_block(works: Workload, latencies: jax.Array,
 
     The kernel path schedules pre-grouped streams, so its in-VMEM block
     (``ClientMerge.lats``/``lats_valid``) holds GROUPED-STEP latencies;
-    `run_stream` instead scatters step latencies back to original
+    `run_stream` instead relocates step latencies back to original
     request order (duplicate same-object requests share their step's
     bits).  This helper replays the identical window split + grouping
     and recovers each step's latency with a ``segment_min`` over its
@@ -287,7 +314,7 @@ def grouped_latency_block(works: Workload, latencies: jax.Array,
         if not group_steps:
             return (jnp.where(val, lat_w, 0.0).reshape(-1),
                     val.reshape(-1))
-        grouped, req_to_step = jax.vmap(group_by_object_with_map)(
+        grouped, req_to_step = group_by_object_with_map(
             Workload(object_ids=obj, lengths=lens, valid=val))
         g_lat = jax.vmap(lambda lr, mp, v: jax.ops.segment_min(
             jnp.where(v, lr, jnp.float32(jnp.inf)), mp,
@@ -405,7 +432,7 @@ def _run_stream_kernel(state: SchedState, work: Workload, key: jax.Array, *,
     m = state.n_servers
     n_win, obj, lens, val = _window_split(work, window_size)
     if group_steps:
-        grouped, req_to_step = jax.vmap(group_by_object_with_map)(
+        grouped, req_to_step = group_by_object_with_map(
             Workload(obj, lens, val))
         g_obj, g_lens, g_val = (grouped.object_ids, grouped.lengths,
                                 grouped.valid)
@@ -433,7 +460,7 @@ def _kernel_bookkeeping(state: SchedState, choices, lats, table, wloads,
                         policy: P.PolicyConfig, window_dt: float, n_win: int,
                         window_size: int, r: int) -> ScheduleResult:
     """Host-side bookkeeping the kernel leaves behind, for ONE stream:
-    redirect derivation, grouped-step -> request scatter, per-server
+    redirect derivation, grouped-step -> request relocation, per-server
     assignment counts, probe accounting (from
     ``PolicyConfig.probes_per_request`` — nonzero only for two_choice)
     and the vclock/free_at replay.  Shared by the sequential kernel path
@@ -449,10 +476,9 @@ def _kernel_bookkeeping(state: SchedState, choices, lats, table, wloads,
     lat_w = lats.reshape(n_win, window_size)
     redir_w = (chosen_w != (g_obj % m).astype(jnp.int32)) & g_val
     if req_to_step is not None:
-        take = jax.vmap(lambda a, idx: a[idx])
-        chosen_w = take(chosen_w, req_to_step)
-        lat_w = take(lat_w, req_to_step)
-        redir_w = take(redir_w, req_to_step)
+        chosen_w, lat_w, redir_w = policy_core.permute_from_sorted(
+            req_to_step, (chosen_w, lat_w, redir_w.astype(jnp.int32)))
+        redir_w = redir_w != 0
     lat_w = lat_w * val
     redir_w = redir_w & val
 
@@ -623,7 +649,7 @@ def run_stream_batch(states: SchedState, works: Workload, keys: jax.Array, *,
     def prep(state, work, key):
         _, obj, lens, val = _window_split(work, window_size)
         if group_steps:
-            grouped, req_to_step = jax.vmap(group_by_object_with_map)(
+            grouped, req_to_step = group_by_object_with_map(
                 Workload(obj, lens, val))
             g_obj, g_lens, g_val = (grouped.object_ids, grouped.lengths,
                                     grouped.valid)
@@ -676,7 +702,7 @@ def run_stream_batch(states: SchedState, works: Workload, keys: jax.Array, *,
 
     # host-side bookkeeping: the SAME single-stream helper as the
     # sequential kernel path, vmapped over the batch axes (every op in
-    # it is exact — gathers, bool masks, integer segment sums,
+    # it is exact — one-hot relocations, bool masks, integer segment sums,
     # elementwise f32 adds — so batching cannot drift it).
     book = functools.partial(
         _kernel_bookkeeping, policy=policy, window_dt=window_dt,

@@ -390,6 +390,26 @@ def rank_desc(keys, valid=None, xp=jnp):
     return xp.sum(before.astype(i32), axis=-1), keys
 
 
+def rank_asc(keys):
+    """Rank of every element under ``(key asc, index asc)`` — the integer
+    mirror of :func:`rank_desc` (DESIGN.md §18).
+
+    ``rank[i] = #{k : key_k < key_i  or  (key_k == key_i and k < i)}``,
+    the inverse of the stable ``argsort(keys)`` permutation, from one
+    all-pairs integer comparison over an ``(..., R, R)`` tile.  Also
+    returns ``n_less[i] = #{k : key_k < key_i}``: the sorted position of
+    the first element of ``i``'s run of equal keys, so ``i`` is the
+    first of its run exactly where ``rank[i] == n_less[i]``.  Keys are
+    compared as they are (int32 object ids never pass through float).
+    """
+    idx = _sort_iota(keys.shape, jnp)
+    a, ia = keys[..., :, None], idx[..., :, None]         # self
+    b, ib = keys[..., None, :], idx[..., None, :]         # other
+    n_less = jnp.sum((b < a).astype(jnp.int32), axis=-1)
+    ties_before = jnp.sum(((b == a) & (ib < ia)).astype(jnp.int32), axis=-1)
+    return n_less + ties_before, n_less
+
+
 def _rank_onehot(rank, xp):
     """(..., i, p) boolean: element ``i`` occupies sorted position ``p``."""
     pos = _sort_iota(rank.shape, xp)
@@ -420,7 +440,10 @@ def permute_from_sorted(rank, payloads, xp=jnp):
     ``out[i] = payload[rank[i]]`` — the inverse apply of DESIGN.md §13,
     same single-non-zero-term masked sum as :func:`permute_to_sorted`
     (property-pinned against the one-hot scatter oracle in
-    tests/test_policies.py)."""
+    tests/test_policies.py).  Each output lane reads one position, so
+    ``rank`` need not be a permutation: any in-range index row makes
+    this an exact take (DESIGN.md §18 relocates step results to their
+    requests with it)."""
     oh = _rank_onehot(rank, xp)
     outs = []
     for x in payloads:
