@@ -145,7 +145,7 @@ def group_by_object_with_map(work: Workload) -> Tuple[Workload, jax.Array]:
     ids = jnp.where(work.valid, work.object_ids, big)
     rank, req_to_step = policy_core.rank_asc(ids)
     s_ids, s_len = policy_core.permute_to_sorted(
-        rank, (ids, work.lengths * work.valid))
+        rank, (ids, work.lengths * work.valid), whole_tile=True)
     is_first = jnp.concatenate(
         [jnp.ones(s_ids.shape[:-1] + (1,), bool),
          s_ids[..., 1:] != s_ids[..., :-1]], axis=-1)
@@ -246,7 +246,8 @@ def run_window(state: SchedState, work: Workload, key: jax.Array, *,
     latencies = lat_sorted * work.valid
     if req_to_step is not None:
         chosen, redirected, latencies = policy_core.permute_from_sorted(
-            req_to_step, (chosen, redirected.astype(jnp.int32), latencies))
+            req_to_step, (chosen, redirected.astype(jnp.int32), latencies),
+            whole_tile=True)
         redirected = (redirected != 0) & orig_work.valid
         latencies = latencies * orig_work.valid
     probes = (jnp.sum(work.valid) * policy.probes_per_request).astype(jnp.int32)
@@ -477,7 +478,8 @@ def _kernel_bookkeeping(state: SchedState, choices, lats, table, wloads,
     redir_w = (chosen_w != (g_obj % m).astype(jnp.int32)) & g_val
     if req_to_step is not None:
         chosen_w, lat_w, redir_w = policy_core.permute_from_sorted(
-            req_to_step, (chosen_w, lat_w, redir_w.astype(jnp.int32)))
+            req_to_step, (chosen_w, lat_w, redir_w.astype(jnp.int32)),
+            whole_tile=True)
         redir_w = redir_w != 0
     lat_w = lat_w * val
     redir_w = redir_w & val

@@ -361,6 +361,97 @@ def bitonic_apply_inverse(order, payloads, xp=jnp):
     return payloads
 
 
+# Lane block of the all-pairs rank and its permutation applies (DESIGN.md
+# §19): one vreg's 128 lanes.  A row wider than one block is cut into
+# ceil(R / 128) blocks on BOTH axes of the all-pairs tile, and the blocks
+# are walked by a loop, so the live tile is (..., 128, 128) at any width;
+# a row of at most 128 is one block: the whole-tile form itself.
+RANK_BLOCK = 128
+
+
+def _n_blocks(r: int) -> int:
+    return -(-r // RANK_BLOCK)
+
+
+def _pad_row(x, n: int, value, xp):
+    """Pad the last axis to ``n`` whole blocks with ``value``."""
+    width = n * RANK_BLOCK
+    if n == 1 or x.shape[-1] == width:
+        return x
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])]
+    return xp.pad(x, pad, constant_values=value)
+
+
+def _block_loop(n: int, body, init, xp):
+    """``acc = body(b, acc)`` for the blocks ``b = 0 .. n-1``: a Python
+    loop over static blocks in numpy, one ``fori_loop`` over a traced
+    block in jnp — a loop, not n unrolled copies, so a Pallas body keeps
+    one block's tile live (Mosaic keeps every unrolled tile)."""
+    if xp is np:
+        for b in range(n):
+            init = body(b, init)
+        return init
+    return jax.lax.fori_loop(0, n, body, init)
+
+
+def _take_block(x, b, n: int, xp):
+    """Lanes ``[128 b, 128 b + 128)`` of a row of ``n`` whole blocks.  A
+    traced ``b`` picks the block with a select chain over the n static
+    blocks: exact for any value, and no lane offset Mosaic must prove."""
+    if xp is np:
+        return x[..., b * RANK_BLOCK:(b + 1) * RANK_BLOCK]
+    out = x[..., :RANK_BLOCK]
+    for k in range(1, n):
+        out = jnp.where(b == k, x[..., k * RANK_BLOCK:(k + 1) * RANK_BLOCK],
+                        out)
+    return out
+
+
+def _place_block(row, blk, b, n: int):
+    """``row`` with its block ``b`` (traced) replaced by ``blk``: one
+    select against the block repeated over the row."""
+    lane_block = jax.lax.broadcasted_iota(jnp.int32, row.shape,
+                                          row.ndim - 1) // RANK_BLOCK
+    return jnp.where(lane_block == b, jnp.concatenate([blk] * n, axis=-1),
+                     row)
+
+
+def _fill_blocks(n: int, block, rows, xp):
+    """Rows of ``n`` whole blocks, shaped and typed as the tuple ``rows``,
+    whose block ``b`` is the tuple ``block(b)``."""
+    if xp is np:
+        parts = [block(b) for b in range(n)]
+        return tuple(np.concatenate(col, axis=-1) for col in zip(*parts))
+    return jax.lax.fori_loop(
+        0, n, lambda b, acc: tuple(_place_block(row, blk, b, n)
+                                   for blk, row in zip(block(b), acc)),
+        tuple(jnp.zeros_like(x) for x in rows))
+
+
+def _block_lanes(lane, b):
+    """Row indices of the lanes of block ``b``: ``lane`` is the iota of
+    one block (int32, exact)."""
+    offset = b * RANK_BLOCK
+    return lane + offset
+
+
+def _sum_blocks(n: int, term, blocks, xp):
+    """``sum_b term(b)`` over the n blocks, from exact zeros shaped and
+    typed as the tuple ``blocks``.  Used only for integer counts, or
+    where one block holds the one non-zero term of each lane: exact in
+    any order."""
+    return _block_loop(
+        n, lambda b, acc: tuple(x + t for x, t in zip(acc, term(b))),
+        tuple(xp.zeros_like(x) for x in blocks), xp)
+
+
+def _count_before(a, ia, b, ib, i32, xp):
+    """Per self element, how many others precede it in ``(key desc,
+    index asc)``: self on axis -2, others on the last axis."""
+    before = (b > a) | ((b == a) & (ib < ia))
+    return xp.sum(before.astype(i32), axis=-1)
+
+
 def rank_desc(keys, valid=None, xp=jnp):
     """Rank of every element under ``(key desc, index asc)`` — the sort
     permutation WITHOUT running a sort network (DESIGN.md §13).
@@ -376,6 +467,12 @@ def rank_desc(keys, valid=None, xp=jnp):
     counts only — bit-exact on every backend, and unlike the network this
     needs no power-of-two padding.
 
+    Wider than :data:`RANK_BLOCK` (DESIGN.md §19), the tile is taken one
+    ``(..., 128, 128)`` block pair at a time: each self block sums the
+    integer counts of every other block.  Padding lanes carry ``-inf``
+    keys and indices past R, so they never precede a real element, and
+    the rank is the same integers at any block count.
+
     Returns ``(rank, masked_keys)``: ``rank`` int32 (..., R), and the
     keys after the validity mask (``-inf`` at invalid rows — the
     ``sorted_keys`` source for :func:`permute_to_sorted`).
@@ -383,11 +480,28 @@ def rank_desc(keys, valid=None, xp=jnp):
     i32 = np.int32 if xp is np else jnp.int32
     if valid is not None:
         keys = xp.where(valid, keys, xp.asarray(-xp.inf, keys.dtype))
-    idx = _sort_iota(keys.shape, xp)
-    a, ia = keys[..., :, None], idx[..., :, None]         # self
-    b, ib = keys[..., None, :], idx[..., None, :]         # other
-    before = (b > a) | ((b == a) & (ib < ia))
-    return xp.sum(before.astype(i32), axis=-1), keys
+    r = keys.shape[-1]
+    n = _n_blocks(r)
+    if n == 1:
+        idx = _sort_iota(keys.shape, xp)
+        return _count_before(keys[..., :, None], idx[..., :, None],
+                             keys[..., None, :], idx[..., None, :], i32,
+                             xp), keys
+    padded = _pad_row(keys, n, -xp.inf, xp)
+    lane = _sort_iota(keys.shape[:-1] + (RANK_BLOCK,), xp)
+
+    def self_block(i):
+        a = _take_block(padded, i, n, xp)[..., :, None]
+        ia = _block_lanes(lane, i)[..., :, None]
+        return _sum_blocks(
+            n, lambda j: (_count_before(
+                a, ia, _take_block(padded, j, n, xp)[..., None, :],
+                _block_lanes(lane, j)[..., None, :], i32, xp),),
+            (lane,), xp)
+
+    (rank,) = _fill_blocks(n, self_block, (xp.zeros(padded.shape, i32),),
+                           xp)
+    return rank[..., :r], keys
 
 
 def rank_asc(keys):
@@ -416,7 +530,7 @@ def _rank_onehot(rank, xp):
     return rank[..., :, None] == pos[..., None, :]
 
 
-def permute_to_sorted(rank, payloads, xp=jnp):
+def permute_to_sorted(rank, payloads, xp=jnp, whole_tile=False):
     """Gather payload lanes into sorted order: ``out[p] = payload[i]``
     where ``rank[i] == p`` (DESIGN.md §13).
 
@@ -425,31 +539,72 @@ def permute_to_sorted(rank, payloads, xp=jnp):
     term per output lane and is therefore a pure relocation — bit-exact
     for floats too (``x + 0.0 == x``; no value here is ``-0.0``).  One
     ``(..., R, R)`` select + sum per payload, no gather op, no sort
-    network — legal inside a fused Pallas body.
+    network — legal inside a fused Pallas body.  Wider than
+    :data:`RANK_BLOCK` (DESIGN.md §19), each 128-position block of the
+    output sums the blocks of the input one ``(..., 128, 128)`` tile at a
+    time; the one non-zero term still lands unchanged.  ``whole_tile``
+    keeps the one tile at any width: XLA fuses it into its reductions
+    and never writes it out, so the engine's grouping takes it (§18); a
+    Pallas body would hold it in VMEM.
     """
-    oh = _rank_onehot(rank, xp)
-    outs = []
-    for x in payloads:
-        z = xp.zeros((), x.dtype)
-        outs.append(xp.sum(xp.where(oh, x[..., :, None], z), axis=-2))
-    return tuple(outs)
+    r = rank.shape[-1]
+    n = 1 if whole_tile else _n_blocks(r)
+    if n == 1:
+        oh = _rank_onehot(rank, xp)
+        return tuple(
+            xp.sum(xp.where(oh, x[..., :, None], xp.zeros((), x.dtype)),
+                   axis=-2) for x in payloads)
+    rank = _pad_row(rank, n, -1, xp)        # padding lands nowhere
+    lane = _sort_iota(rank.shape[:-1] + (RANK_BLOCK,), xp)
+    xs = tuple(_pad_row(x, n, 0, xp) for x in payloads)
+
+    def position_block(p):
+        pos = _block_lanes(lane, p)[..., None, :]
+
+        def term(i):
+            oh = _take_block(rank, i, n, xp)[..., :, None] == pos
+            return tuple(xp.sum(xp.where(oh, _take_block(x, i, n, xp)[
+                ..., :, None], xp.zeros((), x.dtype)), axis=-2) for x in xs)
+
+        return _sum_blocks(n, term, tuple(x[..., :RANK_BLOCK] for x in xs),
+                           xp)
+
+    return tuple(x[..., :r] for x in _fill_blocks(n, position_block, xs, xp))
 
 
-def permute_from_sorted(rank, payloads, xp=jnp):
+def permute_from_sorted(rank, payloads, xp=jnp, whole_tile=False):
     """Scatter sorted payload lanes back to original-index order:
     ``out[i] = payload[rank[i]]`` — the inverse apply of DESIGN.md §13,
-    same single-non-zero-term masked sum as :func:`permute_to_sorted`
-    (property-pinned against the one-hot scatter oracle in
-    tests/test_policies.py).  Each output lane reads one position, so
-    ``rank`` need not be a permutation: any in-range index row makes
-    this an exact take (DESIGN.md §18 relocates step results to their
-    requests with it)."""
-    oh = _rank_onehot(rank, xp)
-    outs = []
-    for x in payloads:
-        z = xp.zeros((), x.dtype)
-        outs.append(xp.sum(xp.where(oh, x[..., None, :], z), axis=-1))
-    return tuple(outs)
+    same single-non-zero-term masked sum as :func:`permute_to_sorted`,
+    blocked the same way past :data:`RANK_BLOCK` (property-pinned against
+    the one-hot scatter oracle in tests/test_policies.py).  Each output
+    lane reads one position, so ``rank`` need not be a permutation: any
+    in-range index row makes this an exact take (DESIGN.md §18 relocates
+    step results to their requests with it).  ``whole_tile`` as in
+    :func:`permute_to_sorted`."""
+    r = rank.shape[-1]
+    n = 1 if whole_tile else _n_blocks(r)
+    if n == 1:
+        oh = _rank_onehot(rank, xp)
+        return tuple(
+            xp.sum(xp.where(oh, x[..., None, :], xp.zeros((), x.dtype)),
+                   axis=-1) for x in payloads)
+    rank = _pad_row(rank, n, -1, xp)
+    lane = _sort_iota(rank.shape[:-1] + (RANK_BLOCK,), xp)
+    xs = tuple(_pad_row(x, n, 0, xp) for x in payloads)
+
+    def index_block(i):
+        ri = _take_block(rank, i, n, xp)[..., :, None]
+
+        def term(p):
+            oh = ri == _block_lanes(lane, p)[..., None, :]
+            return tuple(xp.sum(xp.where(oh, _take_block(x, p, n, xp)[
+                ..., None, :], xp.zeros((), x.dtype)), axis=-1) for x in xs)
+
+        return _sum_blocks(n, term, tuple(x[..., :RANK_BLOCK] for x in xs),
+                           xp)
+
+    return tuple(x[..., :r] for x in _fill_blocks(n, index_block, xs, xp))
 
 
 def recursive_average_bounds(sorted_len, nvalid, n_levels: int, xp=jnp):

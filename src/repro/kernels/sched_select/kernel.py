@@ -83,7 +83,11 @@ comparison per ranking instead of a compare-exchange network — and
 `policy_core.permute_to_sorted`, which lands obj/len/valid (and the
 server ids) in sorted order as a single masked-sum permutation apply
 (no gather op; ``jnp.argsort`` does not lower inside a fused Pallas
-body, and its tie/tree behaviour is a backend choice).  The comparator
+body, and its tie/tree behaviour is a backend choice).  Rows wider than
+one vreg (``M_pad`` or ``ws_pad`` over 128) rank and apply in 128-lane
+blocks walked by loops (DESIGN.md §19), so the live all-pairs tile is
+``(s_tile, 128, 128)`` at any width; a one-vreg row is the whole tile
+itself.  The comparator
 is a strict total order, so the permutation equals the engine's stable
 ``argsort`` bit-for-bit (the window's padding lanes rank after every
 real request, so positions ``[0, window_size)`` are unchanged); nLTR's
@@ -237,7 +241,7 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
         val_w = req_read(valid_ref, w) != 0
 
         if policy in ("trh", "mlml", "nltr") and do_plan:
-            # Window-start plan (DESIGN.md §13): rank servers by
+            # Window-start plan (DESIGN.md §13, §19): rank servers by
             # probability desc with ONE all-pairs comparison, then land
             # the server ids in rank order with a single permutation
             # apply.  Padding lanes get -inf keys so positions [0, M)

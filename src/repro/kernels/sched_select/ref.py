@@ -95,7 +95,7 @@ def sched_stream_ref(object_ids: jax.Array, lengths: jax.Array,
     per-request decision math, per-window renormalization and drain; the
     sort-based policies (mlml/nltr) replay the kernel's in-VMEM window
     plan — the shared all-pairs rank / permutation-apply primitives and
-    recursive-average section bounds (DESIGN.md §10, §13) — processing
+    recursive-average section bounds (DESIGN.md §10, §13, §19) — processing
     in length-desc order and unsorting decisions with the same inverse
     apply.
     """

@@ -430,6 +430,44 @@ def test_run_stream_batch_engine_parity():
             np.asarray(jnp.max(w_open[None] + seq.latencies, axis=-1)))
 
 
+@pytest.mark.parametrize("policy", ["mlml", "nltr", "trh"])
+def test_blocked_window_plan_engine_parity(policy):
+    """300 servers and windows of 260 requests: the window plan's ranks
+    and applies run in three 128-lane blocks on both axes (DESIGN.md
+    §19).  The batched kernel == the vmapped jax engine, bit for bit,
+    over a transient trace."""
+    t, m, r, win = 8, 300, 520, 260
+    trace = _transient_trace(m, slow_ids=(3, 150, 299))
+    cfg = LogConfig(n_servers=m, lam=50.0)
+    keys = jax.random.split(jax.random.key(15), t)
+    rng = np.random.default_rng(15)
+    works = Workload(
+        jnp.asarray(rng.integers(0, 8 * m, (t, r)), jnp.int32),
+        jnp.asarray(rng.uniform(1.0, 20.0, (t, r)), jnp.float32),
+        jnp.ones((t, r), bool))
+    state = statlog.init_state(cfg, rates=trace.rates[0])
+    states = jax.tree.map(lambda a: jnp.broadcast_to(a, (t,) + a.shape),
+                          state)
+    traces = jax.tree.map(lambda a: jnp.broadcast_to(a, (t,) + a.shape),
+                          trace)
+    pol = PolicyConfig(name=policy, threshold=4.0, rng="lcg")
+    batch, _, _ = engine.run_stream_batch(
+        states, works, keys, policy=pol, log_cfg=cfg, window_size=win,
+        traces=traces, window_dt=0.04, observe=True)
+    eng = jax.vmap(lambda w, k: engine.run_stream(
+        state, w, k, policy=pol, log_cfg=cfg, window_size=win, trace=trace,
+        window_dt=0.04, observe=True, backend="jax"))(works, keys)
+    for f in ("chosen", "latencies", "redirected", "window_loads"):
+        np.testing.assert_array_equal(np.asarray(getattr(batch, f)),
+                                      np.asarray(getattr(eng, f)),
+                                      err_msg=f)
+    np.testing.assert_array_equal(np.asarray(batch.state.n_assigned),
+                                  np.asarray(eng.state.n_assigned))
+    np.testing.assert_array_equal(
+        np.asarray(batch.state.log[:, policy_core.ROW_LOADS]),
+        np.asarray(eng.state.log[:, policy_core.ROW_LOADS]))
+
+
 @pytest.mark.parametrize("policy", ["mlml", "nltr"])
 def test_stream_batch_sort_policy_all_invalid_final_window(policy):
     """A FULLY padded (all-invalid) final window: every sort key in the

@@ -506,3 +506,60 @@ def test_rank_desc_equals_argsort_and_network():
         inv = np.empty(33, np.int64)
         inv[ref] = np.arange(33)
         np.testing.assert_array_equal(np.asarray(rank2)[i], inv)
+
+
+def _whole_tile_rank(keys):
+    """The rank as one all-pairs ``(..., R, R)`` tile, unblocked."""
+    idx = np.arange(keys.shape[-1])
+    a, ia = keys[..., :, None], idx[:, None]
+    b, ib = keys[..., None, :], idx[None, :]
+    return np.sum((b > a) | ((b == a) & (ib < ia)), axis=-1)
+
+
+def _whole_tile_apply(onehot, x, axis):
+    return np.sum(np.where(onehot, x, np.zeros((), x.dtype)), axis=axis)
+
+
+@pytest.mark.parametrize("r", [128, 256, 300, 384, 1008, 1024])
+def test_blocked_rank_and_applies_equal_the_whole_tile(r):
+    """Past one 128-lane block (DESIGN.md §19) the rank sums integer
+    counts block pair by block pair and both applies fill their output
+    one block at a time, so every width gives the whole tile's bits:
+    the inverse of the stable argsort(-keys), the take along it and its
+    one-hot scatter.  Ties on every key, an all-invalid row, widths that
+    pad to whole blocks (300, 1008), both xp twins."""
+    rng = np.random.default_rng(r)
+    t = 3
+    keys = rng.choice(np.linspace(0.0, 2.0, 4), (t, r)).astype(np.float32)
+    valid = rng.random((t, r)) > 0.25
+    valid[1] = False
+    masked = np.where(valid, keys, -np.inf).astype(np.float32)
+    order = np.argsort(-masked, axis=-1, kind="stable")
+    whole = _whole_tile_rank(masked)
+    np.testing.assert_array_equal(whole, np.argsort(order, axis=-1))
+    onehot = whole[..., :, None] == np.arange(r)          # (t, i, p)
+    obj = rng.integers(0, 8 * r, (t, r)).astype(np.int32)
+    lat = rng.uniform(0.0, 9.0, (t, r)).astype(np.float32)
+    sorted_obj = _whole_tile_apply(onehot, obj[..., :, None], -2)
+    np.testing.assert_array_equal(sorted_obj,
+                                  np.take_along_axis(obj, order, -1))
+    unsorted_lat = _whole_tile_apply(onehot, lat[..., None, :], -1)
+    take = rng.integers(0, r, (t, r))                     # not a permutation
+    for xp, as_a in ((np, np.asarray), (jnp, jnp.asarray)):
+        rank, mkeys = policy_core.rank_desc(as_a(keys), valid=as_a(valid),
+                                            xp=xp)
+        np.testing.assert_array_equal(np.asarray(rank), whole)
+        for one_tile in (False, True):  # blocks, and the engine's tile
+            obj_s, key_s = policy_core.permute_to_sorted(
+                rank, (as_a(obj), mkeys), xp=xp, whole_tile=one_tile)
+            np.testing.assert_array_equal(np.asarray(obj_s), sorted_obj)
+            np.testing.assert_array_equal(
+                np.asarray(key_s), np.take_along_axis(masked, order, -1))
+            back, obj_back = policy_core.permute_from_sorted(
+                rank, (as_a(lat), obj_s), xp=xp, whole_tile=one_tile)
+            np.testing.assert_array_equal(np.asarray(back), unsorted_lat)
+            np.testing.assert_array_equal(np.asarray(obj_back), obj)
+            (taken,) = policy_core.permute_from_sorted(
+                as_a(take), (as_a(lat),), xp=xp, whole_tile=one_tile)
+            np.testing.assert_array_equal(np.asarray(taken),
+                                          np.take_along_axis(lat, take, -1))

@@ -76,6 +76,21 @@ def test_trial_grid_compiles_for_v5e(one_chip, policy):
              **_common(policy))
 
 
+@pytest.mark.parametrize("policy", ["ect", "trh", "mlml", "nltr"])
+def test_spider2_namespace_compiles_for_v5e(one_chip, policy):
+    """One Spider II namespace: 1,008 servers and windows of 1,008, 32
+    trials.  The window plan's ranks and applies run in 128-lane blocks
+    (DESIGN.md §19); whole (1,024, 1,024) tiles were refused for scoped
+    VMEM."""
+    m, t, window, n_win = 1008, 32, 1008, 20
+    r = n_win * window
+    shapes = [((t, r), jnp.int32), ((t, r), jnp.float32),
+              ((t, r), jnp.bool_), ((t, 4, m), jnp.float32),
+              ((t,), jnp.uint32), ((t, n_win, m), jnp.float32)]
+    _compile(ops.sched_stream_batch, shapes, one_chip, window_size=window,
+             **dict(_common(policy), n_servers=m))
+
+
 @pytest.mark.parametrize("n_clients", [4, 64, 200])
 def test_client_grid_compiles_for_v5e(one_chip, n_clients):
     # the per_client split of simulate._client_split_shape
