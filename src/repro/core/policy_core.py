@@ -92,18 +92,77 @@ def resolve_client_tile(n_clients: int, client_tile=None) -> int:
 
 # trials per program instance in the trial-grid form: the sublane count
 # of the native f32 (8, 128) TPU tile, so each vectorized table op fills
-# whole tiles instead of one sublane in eight.
+# whole tiles instead of one sublane in eight.  Also the floor of the
+# shape rule below.
 DEFAULT_TRIAL_TILE = 8
 
+# The trial-grid tile by row depth (DESIGN.md §20).  Each serial step of
+# the kernel is a chain of dependent cross-lane reductions over
+# (s_tile, M_pad) rows: at one vreg a row the chain's latency leaves the
+# vector units idle, so a deeper tile runs more trials for nearly the
+# same step time, until a row of ROW_VREG_BUDGET vregs fills them.
+ROW_VREG_BUDGET = 8
+SUBLANES, LANES = 8, 128    # one f32 vreg; LANES also aligns a window
+# The window plan's live all-pairs tiles, each (s_tile, 128, 128) f32
+# (policies with a plan: `PLAN_POLICIES`), and the scoped VMEM the kernel
+# compiles with (Mosaic's default; the kernel sets no vmem_limit_bytes).
+PLAN_POLICIES = ("trh", "mlml", "nltr")
+PLAN_TILES = 5
+SCOPED_VMEM_BYTES = 16 << 20
 
-def resolve_trial_tile(n_trials: int, trial_tile=None) -> int:
-    """Effective trials-per-block of the trial grid.  The tile is a
+
+def _whole(n: int, unit: int) -> int:
+    return -(-n // unit) * unit
+
+
+def trial_grid_vmem_bytes(s_tile: int, n_servers: int, window_size: int,
+                          n_windows: int, plan: bool) -> int:
+    """VMEM one trial-grid program of ``s_tile`` streams asks for, in
+    bytes: the double-buffered request, choice and latency blocks, the
+    table, rate, decrement, window-load, seed and metrics blocks, the
+    table scratch and, with a window plan, its ``(s_tile, 128, 128)``
+    tiles.  Words of 4 bytes, in (8, 128) tiles."""
+    m_pad = _whole(n_servers, LANES)
+    buffered = sum((5 * n_windows * _whole(window_size, LANES),
+                    2 * _whole(N_ROWS, SUBLANES) * m_pad,
+                    3 * _whole(n_windows, SUBLANES) * m_pad,
+                    2 * LANES))
+    per_stream = sum((2 * buffered, N_ROWS * m_pad,
+                      PLAN_TILES * LANES * LANES if plan else 0))
+    return 4 * _whole(s_tile, SUBLANES) * per_stream
+
+
+def resolve_trial_tile(n_trials: int, trial_tile=None, *, n_servers=None,
+                       n_requests=None, window_size=None, policy=None,
+                       n_devices: int = 1) -> int:
+    """Effective trials-per-block of the trial grid, for the trials one
+    device runs (``ceil(n_trials / n_devices)``).  The tile is a
     lowering parameter (XLA specializes the block shape to it), so the
     kernel dispatch, the sharded sweep and the engine must all resolve
-    it through here — resolving it anywhere else risks two layers
-    disagreeing on the association (DESIGN.md §12)."""
-    tt = DEFAULT_TRIAL_TILE if trial_tile is None else trial_tile
-    return max(min(tt, n_trials), 1)
+    it through here (DESIGN.md §12).
+
+    An explicit ``trial_tile`` wins, clamped to the trials.  Unset, the
+    tile is ``DEFAULT_TRIAL_TILE`` unless the shapes are given
+    (``n_servers``, ``n_requests`` per stream, ``window_size`` and
+    ``policy``; the trial grid's dispatch gives them): then it is the
+    deepest multiple of ``SUBLANES`` whose ``(s_tile, M_pad)`` row stays
+    within ``ROW_VREG_BUDGET`` vregs and whose program stays within
+    ``SCOPED_VMEM_BYTES`` (`trial_grid_vmem_bytes`), never shallower than
+    the default, balanced over the programs it takes (DESIGN.md §20)."""
+    per = max(-(-n_trials // n_devices), 1)
+    if trial_tile is not None or n_servers is None:
+        tt = DEFAULT_TRIAL_TILE if trial_tile is None else trial_tile
+        return max(min(tt, per), 1)
+    n_windows = -(-n_requests // window_size)
+    plan = policy in PLAN_POLICIES
+    deepest = max(ROW_VREG_BUDGET * LANES // _whole(n_servers, LANES)
+                  * SUBLANES, DEFAULT_TRIAL_TILE)
+    while deepest > DEFAULT_TRIAL_TILE and trial_grid_vmem_bytes(
+            deepest, n_servers, window_size, n_windows,
+            plan) > SCOPED_VMEM_BYTES:
+        deepest -= SUBLANES
+    programs = -(-per // deepest)
+    return -(-per // programs)
 
 
 # Sublane budget of the FUSED multi-trial client block (DESIGN.md §16):

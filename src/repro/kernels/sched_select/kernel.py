@@ -122,17 +122,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.policy_core import (LCG_A, LCG_C, MET_LAT_MAX, MET_LAT_SUM,
-                                    MET_MAKESPAN, MET_N_VALID, MET_P99,
-                                    MET_PAD, N_ROWS, P99_BISECT_ITERS, P99_Q,
-                                    ROW_EST, ROW_EWMA, ROW_LOADS, ROW_PROBS,
-                                    lane_sum, permute_from_sorted,
-                                    permute_to_sorted, rank_desc,
-                                    recursive_average_bounds,
+from repro.core.policy_core import (LANES, LCG_A, LCG_C, MET_LAT_MAX,
+                                    MET_LAT_SUM, MET_MAKESPAN, MET_N_VALID,
+                                    MET_P99, MET_PAD, N_ROWS, P99_BISECT_ITERS,
+                                    P99_Q, PLAN_POLICIES, ROW_EST, ROW_EWMA,
+                                    ROW_LOADS, ROW_PROBS, lane_sum,
+                                    permute_from_sorted, permute_to_sorted,
+                                    rank_desc, recursive_average_bounds,
                                     window_decrements)
 
 _BIG = 3.4e38  # padding-lane load: never selected, never drained
-LANES = 128    # lanes per TPU vreg: the alignment unit of a window
 
 
 def window_lanes(window_size: int) -> int:
@@ -240,7 +239,7 @@ def _sched_stream_kernel(objs_ref, lens_ref, valid_ref, table_ref, seed_ref,
         len_w = req_read(lens_ref, w)
         val_w = req_read(valid_ref, w) != 0
 
-        if policy in ("trh", "mlml", "nltr") and do_plan:
+        if policy in PLAN_POLICIES and do_plan:
             # Window-start plan (DESIGN.md §13, §19): rank servers by
             # probability desc with ONE all-pairs comparison, then land
             # the server ids in rank order with a single permutation

@@ -185,8 +185,10 @@ def sched_stream_batch(object_ids: jax.Array, lengths: jax.Array,
     multiple of ``trial_tile`` with inert trials (all-invalid requests,
     fresh tables, unit rates) and the grid runs ``ceil(T / trial_tile)``
     program instances, each vectorizing its tile of trials over VMEM
-    sublanes — bit-exact per trial vs. mapping :func:`sched_stream`
-    sequentially (asserted in tests/test_kernels.py).
+    sublanes (unset, the tile follows the row depth:
+    `policy_core.resolve_trial_tile`, DESIGN.md §20) — bit-exact per
+    trial vs. mapping :func:`sched_stream` sequentially (asserted in
+    tests/test_kernels.py).
 
     Returns (choices (T, N) int32, latencies (T, N) f32, final_tables
     (T, 4, M) f32, window_loads (T, W, M) f32, metrics (T, N_METRICS)
@@ -201,7 +203,8 @@ def sched_stream_batch(object_ids: jax.Array, lengths: jax.Array,
     interpret = _auto_interpret(interpret)
     t, n = object_ids.shape
     m = tables.shape[-1]
-    tile = resolve_trial_tile(t, trial_tile)
+    tile = resolve_trial_tile(t, trial_tile, n_servers=m, n_requests=n,
+                              window_size=window_size, policy=policy)
     t_pad = -(-t // tile) * tile
     m_pad = _pad_servers(m)
     if t_pad != t:

@@ -124,16 +124,23 @@ def run_sweep(states, works, keys, *, mesh_shape: Optional[Tuple[int, ...]],
     if observe is None:
         observe = traces is not None
 
-    # ---- trial-axis padding: replicate trial 0 up to t_dev equal shards
-    # of at least one full trial tile.  The tile is a LOWERING parameter
-    # (XLA specializes elementwise code to the block shape), so it must
-    # resolve on every device exactly as the single-device dispatch
-    # resolves it from the global T: pin the globally-resolved tile and
-    # keep every shard at least that long so `ops`' resolve_trial_tile
+    # ---- trial-axis padding: replicate trial 0 up to t_dev equal shards.
+    # The tile is a LOWERING parameter (XLA specializes elementwise code
+    # to the block shape), so every device runs the one tile resolved
+    # here.  The trial grid's follows the row depth of a shard's trials
+    # (DESIGN.md §20); the 2-D grid's is resolved from the global T, and
+    # every shard is kept at least that long so `ops`' resolve_trial_tile
     # of T_local cannot clamp it differently (DESIGN.md §12).
-    tt_eff = kops.resolve_trial_tile(t, trial_tile)
-    t_loc = max(-(-t // t_dev), tt_eff) if backend == "kernel" \
-        else -(-t // t_dev)
+    t_loc = -(-t // t_dev)
+    if two_d:
+        tt_eff = kops.resolve_trial_tile(t, trial_tile)
+        if backend == "kernel":
+            t_loc = max(t_loc, tt_eff)
+    else:
+        tt_eff = kops.resolve_trial_tile(
+            t, trial_tile, n_servers=states.n_servers,
+            n_requests=works.object_ids.shape[-1], window_size=window_size,
+            policy=policy.name, n_devices=t_dev)
     t_pad = t_loc * t_dev
 
     # ---- client-axis padding: phantoms up to c_dev equal shards (the

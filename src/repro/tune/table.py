@@ -146,9 +146,10 @@ def resolve_sim_tiles(*, mode: str, policy: str, backend: str,
     agreement itself.  Explicit ``trial_tile``/``client_tile`` settings
     always win over the table; ``mode``:
 
-    * ``"default"`` — the static `resolve_trial_tile` /
-      `resolve_client_tile` defaults (the pre-tuner behaviour, and the
-      fallback for every bad-cache state);
+    * ``"default"`` — the `resolve_trial_tile` / `resolve_client_tile`
+      defaults (the fallback for every bad-cache state); for
+      ``form="batch"`` the trial tile follows the row depth, per device
+      (DESIGN.md §20), as the kernel's own default does;
     * ``"fused"``   — the `resolve_grid_tiles` fused multi-trial client
       block (deepen the trial tile when the client tile is small);
     * ``"tuned"``   — the cached autotuner winner for this
@@ -174,5 +175,9 @@ def resolve_sim_tiles(*, mode: str, policy: str, backend: str,
     if mode == "fused":
         return resolve_grid_tiles(n_trials, n_clients, trial_tile,
                                   client_tile)
-    return (resolve_trial_tile(n_trials, trial_tile),
-            resolve_client_tile(n_clients, client_tile))
+    ct = resolve_client_tile(n_clients, client_tile)
+    if form != "batch":
+        return resolve_trial_tile(n_trials, trial_tile), ct
+    return resolve_trial_tile(
+        n_trials, trial_tile, n_servers=n_servers, n_requests=n_requests,
+        window_size=window_size, policy=policy, n_devices=device_count), ct

@@ -295,6 +295,11 @@ BATCH_CASES = [
     (6, 24, 4, 30, 4, "nltr"),
     (3, 24, 3, 30, 3, "rr"),
     (3, 24, 3, 30, 3, "two_choice"),
+    # tile None: the row-depth default (DESIGN.md §20), one program of 20
+    # trials, against trial_tile=8 (three programs, 4 inert trials)
+    (20, 100, 3, 100, None, "ect"),
+    (20, 100, 3, 100, None, "rr"),
+    (20, 100, 2, 100, None, "mlml"),
 ]
 
 
@@ -327,6 +332,17 @@ def test_stream_batch_matches_ref_and_sequential(case):
         np.asarray(tab[:, policy_core.ROW_LOADS]),
         np.asarray(rtab[:, policy_core.ROW_LOADS]))
     np.testing.assert_allclose(np.asarray(tab), np.asarray(rtab), atol=1e-6)
+    if tile is None:
+        # trials are independent sublanes: the default tile's outputs,
+        # final tables whole, are the 8-deep tile's bit for bit
+        deep = policy_core.resolve_trial_tile(
+            t, n_servers=m, n_requests=obj.shape[1], window_size=win,
+            policy=policy)
+        assert deep > 8
+        outs8 = sched_stream_batch(obj, lens, valid, tables, seeds, rates,
+                                   trial_tile=8, **kw)
+        for a, b in zip((ch, lat, tab, wl, met), outs8):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # per-trial sequential kernel (the lax.map path's unit of work)
     for i in range(t):
         c1, l1, _, w1 = sched_stream(obj[i], lens[i], valid[i], tables[i],

@@ -5,6 +5,9 @@ compiled at the paper's §4 widths (100 servers, 2,000 requests, window
 100, 100 trials) for a v5e chip that is described, not attached: the
 compiler refuses here what the chip would refuse (unaligned slices,
 illegal block shapes, too much VMEM), at no chip time.  Nothing runs.
+The trial-grid cases pass no tile, so they compile at the tile the
+shapes give (`policy_core.resolve_trial_tile`, DESIGN.md §20): the one
+the chip runs.
 The whole sweep program, ``simulate.run_trials``, is compiled too, at a
 small shape: each of its ops must sit in a stage scope, and the scopes
 must leave the program and the kernel's name as they were.
@@ -66,7 +69,8 @@ def _common(policy):
                 observe=True, renorm=True)
 
 
-@pytest.mark.parametrize("policy", ["ect", "trh", "mlml", "nltr"])
+@pytest.mark.parametrize("policy",
+                         ["ect", "rr", "two_choice", "trh", "mlml", "nltr"])
 def test_trial_grid_compiles_for_v5e(one_chip, policy):
     n_win = R // WINDOW
     shapes = [((T, R), jnp.int32), ((T, R), jnp.float32), ((T, R), jnp.bool_),

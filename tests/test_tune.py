@@ -18,7 +18,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import simulate
+from repro.core import policy_core, simulate
 from repro.core.engine import KERNEL_POLICIES
 from repro.core.policies import PolicyConfig
 from repro.core.policy_core import (DEFAULT_CLIENT_TILE,
@@ -122,6 +122,69 @@ def test_resolve_grid_tiles_deepens_small_client_blocks():
     assert resolve_grid_tiles(100, 4, trial_tile=8, client_tile=2) == (8, 2)
     # clamps still apply: tiny instances never exceed their extents
     assert resolve_grid_tiles(3, 2) == (3, 2)
+
+
+# ------------------------------------------------- row-depth trial tile
+
+# (policy, trials, servers, requests, window, devices, explicit tile,
+#  form, clients) -> (trial tile, programs per device): the benchmark
+# cells' shapes, resolved where `simulate._sched_trials` resolves them
+PAPER4 = (100, 100, 2000, 100)
+SPIDER2 = (32, 1008, 20160, 1008)
+ROW_DEPTH_CASES = {
+    "paper4_shared_log.ect": (("ect",) + PAPER4 + (1, None, "batch", 1),
+                              (50, 2)),
+    "paper4_shared_log.rr": (("rr",) + PAPER4 + (1, None, "batch", 1),
+                             (50, 2)),
+    "paper4_shared_log.mlml": (("mlml",) + PAPER4 + (1, None, "batch", 1),
+                               (25, 4)),
+    "spider2_1008_shared_log.mlml": (
+        ("mlml",) + SPIDER2 + (1, None, "batch", 1), (8, 4)),
+    "few_trials": (("ect", 5, 100, 2000, 100, 1, None, "batch", 1), (5, 1)),
+    "eight_trials": (("mlml", 8, 100, 2000, 100, 1, None, "batch", 1),
+                     (8, 1)),
+    "trials_mesh_of_4": (("ect",) + PAPER4 + (4, None, "batch", 1),
+                         (25, 1)),
+    "explicit_tile_wins": (("ect",) + PAPER4 + (1, 8, "batch", 1), (8, 13)),
+    "paper4_per_client.grid": (("ect",) + PAPER4 + (1, None, "grid", 200),
+                               (8, 13)),
+    # rows of 3 to 7 vregs, 20 windows a stream: the depth is a whole
+    # number of sublane tiles, never below the default
+    "m300_long.ect": (("ect", 100, 300, 6000, 300, 1, None, "batch", 1),
+                      (15, 7)),
+    "m600_long.mlml": (("mlml", 100, 600, 12000, 600, 1, None, "batch", 1),
+                       (8, 13)),
+    "m700_long.ect": (("ect", 100, 700, 14000, 700, 1, None, "batch", 1),
+                      (8, 13)),
+    "m800_long.ect": (("ect", 100, 800, 16000, 800, 1, None, "batch", 1),
+                      (8, 13)),
+}
+
+
+@pytest.mark.parametrize("case", ROW_DEPTH_CASES.values(),
+                         ids=ROW_DEPTH_CASES.keys())
+def test_trial_tile_follows_row_depth(case):
+    """The trial grid's default tile is the deepest within the row's vreg
+    budget and the scoped VMEM, balanced over its programs, per device
+    (DESIGN.md §20); an explicit tile wins, and the per-client grid keeps
+    its tiles."""
+    (policy, t, m, r, window, devices, tile, form, c), want = case
+    tt, ct = table.resolve_sim_tiles(
+        mode="default", policy=policy, backend="kernel", n_servers=m,
+        n_requests=r, n_clients=c, n_trials=t, window_size=window,
+        device_count=devices, form=form, trial_tile=tile)
+    per = -(-t // devices)
+    assert (tt, -(-per // tt)) == want
+    assert tt >= min(DEFAULT_TRIAL_TILE, per)
+    if form == "grid":
+        assert (tt, ct) == resolve_grid_tiles(t, c) == (DEFAULT_TRIAL_TILE,
+                                                        DEFAULT_CLIENT_TILE)
+    else:
+        assert ct == 1
+        assert policy_core.trial_grid_vmem_bytes(
+            tt, m, window, -(-r // window),
+            policy in policy_core.PLAN_POLICIES) \
+            <= policy_core.SCOPED_VMEM_BYTES or tt == DEFAULT_TRIAL_TILE
 
 
 def test_simconfig_rejects_unknown_tiles_mode():
